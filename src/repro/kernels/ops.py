@@ -1,14 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the CPU container the kernels run in ``interpret=True`` mode (the kernel
-body executes in Python, validating the exact TPU dataflow); on a real TPU
-backend they compile to Mosaic.  The choice is automatic but overridable
-via ``REPRO_PALLAS_INTERPRET``.
+On a TPU backend the kernels compile to Mosaic; on every other backend
+they run in ``interpret=True`` mode (the kernel body executes in Python,
+validating the exact TPU dataflow).  The backend alone decides.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -20,24 +18,22 @@ from .flash_attention import flash_attention
 from .ssd_scan import ssd_scan
 
 
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def interpret_mode(backend: Optional[str] = None) -> bool:
+    """Whether Pallas kernels run interpreted: everywhere but ``tpu``."""
+    return (backend or jax.default_backend()) != "tpu"
 
 
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = True) -> jax.Array:
     """(B,H,S,hd) x (B,K,S,hd)^2 -> (B,H,S,hd)."""
-    return flash_attention(q, k, v, causal=causal, interpret=_interpret())
+    return flash_attention(q, k, v, causal=causal, interpret=interpret_mode())
 
 
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  lengths: jax.Array) -> jax.Array:
     """(B,H,hd) x (B,K,S,hd)^2 + lengths (B,) -> (B,H,hd)."""
     return decode_attention(q, k_cache, v_cache, lengths,
-                            interpret=_interpret())
+                            interpret=interpret_mode())
 
 
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
@@ -50,4 +46,4 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         n = b.shape[-1]
         init_state = jnp.zeros((bsz, h, p, n), jnp.float32)
     return ssd_scan(x, dt, a, b, c, chunk, init_state,
-                    interpret=_interpret())
+                    interpret=interpret_mode())

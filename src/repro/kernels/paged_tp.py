@@ -15,56 +15,49 @@ Inputs that stay REPLICATED across the mesh: block tables, per-row
 lengths (host-side accounting state — serving/paged_kv.py), and the
 span-length vectors.  Only q/k/v/pages are sharded (on their head dim).
 
-Fallback contract (DESIGN.md §Sharded serving): the Pallas kernels are
-TPU kernels; on backends where the sharded Pallas call is unsupported
-(CPU/GPU — anything whose default backend is not ``tpu``) the shard_map
-body falls back to the pure-jnp reference gather path (``kernels.ref``),
-which computes the same math over the same local head slice.  Callers
-can force either body with ``use_kernel=``; interpret mode rides the
-kernel body for CPU kernel validation.
+Body selection (DESIGN.md §Sharded serving): the shard_map body is the
+Pallas kernel, compiled on ``tpu`` and interpreted on every other backend
+(``ops.interpret_mode``).  ``use_kernel=False`` swaps in the pure-jnp
+reference gather (``kernels.ref``) — an oracle for tests, refused on
+``tpu`` so a chip run can never quietly leave the kernel.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import ref
+from .ops import interpret_mode
 from .paged_append_attention import paged_append_attention
 from .paged_decode_attention import paged_decode_attention
 
 
-def sharded_kernel_supported(backend: Optional[str] = None) -> bool:
-    """Whether the sharded Pallas kernel body is expected to run on this
-    backend (compiled Pallas TPU kernels only; everything else takes the
-    documented reference-gather fallback)."""
-    backend = backend or jax.default_backend()
-    return backend == "tpu"
+def _body(kernel: Callable, reference: Callable,
+          use_kernel: bool) -> Callable:
+    """The shard_map body: the Pallas kernel (interpreted off ``tpu``),
+    or — only when asked for, and never on ``tpu`` — the reference."""
+    if use_kernel:
+        return functools.partial(kernel, interpret=interpret_mode())
+    if not interpret_mode():
+        raise ValueError("use_kernel=False: the reference body is a test "
+                         "oracle and is refused on tpu")
+    return reference
 
 
 def tp_paged_decode_attention(mesh, q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array, block_tables: jax.Array,
                               lengths: jax.Array, *, axis: str = "model",
-                              interpret: bool = False,
-                              use_kernel: Optional[bool] = None
-                              ) -> jax.Array:
+                              use_kernel: bool = True) -> jax.Array:
     """Sharded paged flash-decode: q (B, H, hd) and pages (P, K, bs, hd)
     sharded on their head dims over ``axis``; block tables and lengths
-    replicated.  Returns (B, H, hd) sharded like q.  ``use_kernel=None``
-    auto-selects: Pallas body on TPU (or under ``interpret``), reference
-    gather elsewhere."""
-    if use_kernel is None:
-        use_kernel = interpret or sharded_kernel_supported()
-    if use_kernel:
-        body = functools.partial(paged_decode_attention,
-                                 interpret=interpret)
-    else:
-        body = ref.paged_decode_reference
-    fn = shard_map(
+    replicated.  Returns (B, H, hd) sharded like q."""
+    body = _body(paged_decode_attention, ref.paged_decode_reference,
+                 use_kernel)
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis, None),                # q heads
                   P(None, axis, None, None),          # k pages kv-heads
@@ -72,7 +65,7 @@ def tp_paged_decode_attention(mesh, q: jax.Array, k_pages: jax.Array,
                   P(None, None),                      # block tables
                   P(None)),                           # lengths
         out_specs=P(None, axis, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pages, v_pages, block_tables, lengths)
 
 
@@ -81,21 +74,14 @@ def tp_paged_append_attention(mesh, q: jax.Array, k_new: jax.Array,
                               v_pages: jax.Array, block_tables: jax.Array,
                               ctx_lens: jax.Array, span_lens: jax.Array,
                               *, axis: str = "model",
-                              interpret: bool = False,
-                              use_kernel: Optional[bool] = None
-                              ) -> jax.Array:
+                              use_kernel: bool = True) -> jax.Array:
     """Sharded span verification attention: q (B, T, H, hd) and
     k_new/v_new (B, T, K, hd) sharded on their head dims alongside the
     page pool; tables/lengths replicated.  Returns (B, T, H, hd) sharded
     like q.  Same body-selection rule as the decode wrapper."""
-    if use_kernel is None:
-        use_kernel = interpret or sharded_kernel_supported()
-    if use_kernel:
-        body = functools.partial(paged_append_attention,
-                                 interpret=interpret)
-    else:
-        body = ref.paged_append_reference
-    fn = shard_map(
+    body = _body(paged_append_attention, ref.paged_append_reference,
+                 use_kernel)
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, axis, None),          # q heads
                   P(None, None, axis, None),          # k_new kv-heads
@@ -106,6 +92,6 @@ def tp_paged_append_attention(mesh, q: jax.Array, k_new: jax.Array,
                   P(None),                            # ctx_lens
                   P(None)),                           # span_lens
         out_specs=P(None, None, axis, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_new, v_new, k_pages, v_pages, block_tables, ctx_lens,
               span_lens)

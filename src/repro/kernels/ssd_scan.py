@@ -9,12 +9,17 @@ both for one (batch, head) pair:
     *sequential*, so the running SSM state (P, N) lives in VMEM scratch and
     carries across chunk iterations — the inter-chunk recurrence costs no
     HBM traffic at all.
-  * BlockSpec tiles per step: x (Q, P), dt (Q,), B/C (Q, N) with the GQA-
-    style group->head broadcast resolved in the index_map (no repeat in
-    HBM).  Q = chunk length (128 default) keeps every matmul MXU-aligned:
-    (Q,N)x(N,Q), (Q,Q)x(Q,P), (N,Q)x(Q,P).
-  * The decay matrix exp(segsum(a*dt)) is built in-register from a cumsum —
-    cheap VPU work overlapped with the MXU matmuls.
+  * Head-major layouts, so every block's last two dims are TPU tiles
+    (multiples of (8, 128) or whole dims): per step x (Q, P), B^T (N, Q),
+    C (Q, N), with the GQA-style group->head broadcast resolved in the
+    index_map (no repeat in HBM).  Q = chunk length (128 default) keeps
+    every matmul MXU-aligned and untransposed: (Q,N)x(N,Q), (Q,Q)x(Q,P),
+    (Q,N)x(N,P), (N,Q)x(Q,P) — the running state is held as (N, P).
+  * The per-chunk cumulative log-decay cum = cumsum(a*dt) and the decays
+    derived from it are computed by the wrapper (small elementwise
+    passes): cum arrives as a column (Q, 1) and a row (1, Q), so the
+    decay matrix exp(segsum) is a broadcast subtraction — no in-kernel
+    cumsum, vector transpose or scalar broadcast.
 
 Emits both the per-position outputs y (B, L, H, P) and the final state
 (B, H, P, N) — the latter is what SpecReason snapshots at reasoning-step
@@ -32,11 +37,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
-                y_ref, fin_ref, state_ref, *, chunk: int):
+def _ssd_kernel(x_ref, dt_ref, cum_ref, cum_row_ref, tail_ref, decay_ref,
+                bt_ref, c_ref, init_ref, y_ref, fin_ref, state_ref, *,
+                chunk: int):
     ic = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -44,40 +48,33 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
     def _init():
         state_ref[...] = init_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)        # (Q,)
-    a = a_ref[0].astype(jnp.float32)                 # ()
-    b = b_ref[0, :, 0].astype(jnp.float32)           # (Q, N)
-    c = c_ref[0, :, 0].astype(jnp.float32)           # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)            # (Q, 1)
+    cum = cum_ref[0, 0]                              # (Q, 1) f32
+    cum_row = cum_row_ref[0, 0]                      # (1, Q) f32
+    bt = bt_ref[0, 0].astype(jnp.float32)            # (N, Q)
+    c = c_ref[0, 0].astype(jnp.float32)              # (Q, N)
 
-    xd = x * dt[:, None]
-    adt = a * dt                                     # (Q,)
-    cum = jnp.cumsum(adt)                            # (Q,)
-
+    xd = x * dt
     # intra-chunk decay matrix L[i,j] = exp(cum_i - cum_j) for j <= i
-    seg = cum[:, None] - cum[None, :]
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     kj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lmat = jnp.where(kj <= qi, jnp.exp(seg), 0.0)
+    lmat = jnp.where(kj <= qi, jnp.exp(cum - cum_row), 0.0)
 
-    scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * lmat
-    y = jax.lax.dot_general(scores, xd, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    scores = jnp.dot(c, bt, preferred_element_type=jnp.float32) * lmat
+    y = jnp.dot(scores, xd, preferred_element_type=jnp.float32)
 
     # contribution of the state entering this chunk
-    state = state_ref[...]                            # (P, N)
-    c_dec = c * jnp.exp(cum)[:, None]                 # (Q, N)
-    y = y + jax.lax.dot_general(c_dec, state, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
+    state = state_ref[...]                            # (N, P)
+    y = y + jnp.dot(c * jnp.exp(cum), state,
+                    preferred_element_type=jnp.float32)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    # state update: state' = state * exp(sum a dt) + sum_j decay_j * x_j b_j^T
-    decay_states = jnp.exp(cum[-1] - cum)             # (Q,)
-    xb = jax.lax.dot_general(xd * decay_states[:, None], b,
-                             (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (P, N)
-    state_ref[...] = state * jnp.exp(cum[-1]) + xb
+    # state update: state' = state * exp(sum a dt)
+    #                        + sum_j exp(cum_end - cum_j) b_j x_j^T
+    xb = jnp.dot(bt, xd * tail_ref[0, 0],
+                 preferred_element_type=jnp.float32)  # (N, P)
+    state_ref[...] = state * decay_ref[0, 0, 0] + xb
 
     @pl.when(ic == nc - 1)
     def _emit():
@@ -98,30 +95,50 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     nc = l // chunk
     rep = h // g
 
+    dt_h = dt.transpose(0, 2, 1)                              # (B, H, L)
+    adt = a.astype(jnp.float32)[None, :, None] * dt_h.astype(jnp.float32)
+    cum = jnp.cumsum(adt.reshape(bsz, h, nc, chunk), axis=-1)
+    total = cum[..., -1:]                                     # (B, H, nc, 1)
+    tail = jnp.exp(total - cum).reshape(bsz, h, l, 1)
+    # per-chunk state decay, lane-broadcast to (1, P) here: Mosaic
+    # cannot broadcast a (1, 1) value over sublanes and lanes at once
+    decay = jnp.broadcast_to(jnp.exp(total)[..., None], (bsz, h, nc, 1, p))
+    cum = cum.reshape(bsz, h, l)
+
+    def col(ib, ih, ic):
+        return ib, ih, ic, 0
+
     grid = (bsz, h, nc)
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y, fin = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda ib, ih, ic: (ib, ic, ih)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
-            pl.BlockSpec((1, chunk, 1, n),
-                         lambda ib, ih, ic, r=rep: (ib, ic, ih // r, 0)),
-            pl.BlockSpec((1, chunk, 1, n),
-                         lambda ib, ih, ic, r=rep: (ib, ic, ih // r, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, p), col),
+            pl.BlockSpec((1, 1, chunk, 1), col),
+            pl.BlockSpec((1, 1, chunk, 1), col),
+            pl.BlockSpec((1, 1, 1, chunk), lambda ib, ih, ic: (ib, ih, 0, ic)),
+            pl.BlockSpec((1, 1, chunk, 1), col),
+            pl.BlockSpec((1, 1, 1, 1, p),
+                         lambda ib, ih, ic: (ib, ih, ic, 0, 0)),
+            pl.BlockSpec((1, 1, n, chunk),
+                         lambda ib, ih, ic, r=rep: (ib, ih // r, 0, ic)),
+            pl.BlockSpec((1, 1, chunk, n),
+                         lambda ib, ih, ic, r=rep: (ib, ih // r, ic, 0)),
+            pl.BlockSpec((1, 1, n, p), lambda ib, ih, ic: (ib, ih, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, p), col),
+            pl.BlockSpec((1, 1, n, p), lambda ib, ih, ic: (ib, ih, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, l, h, p), x.dtype),
-            jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, h, l, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, n, p), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, b, c, init_state.astype(jnp.float32))
-    return y, fin
+    )(x.transpose(0, 2, 1, 3), dt_h[..., None], cum[..., None],
+      cum[:, :, None, :], tail, decay, b.transpose(0, 2, 3, 1),
+      c.transpose(0, 2, 1, 3),
+      init_state.astype(jnp.float32).transpose(0, 1, 3, 2))
+    return y.transpose(0, 2, 1, 3), fin.transpose(0, 1, 3, 2)

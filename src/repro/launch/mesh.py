@@ -19,23 +19,34 @@ HBM_BW = 819e9                  # B/s
 ICI_BW = 50e9                   # B/s per link
 
 
+def auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: sharding is propagated
+    by GSPMD from the ``constrain`` hints (models/sharding.py).  Under
+    the ``Explicit`` default those hints are refused and a dot whose
+    contracting dim is sharded raises instead of gathering."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
                    axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for CPU distribution tests (8 forced host devices)."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_tp_mesh(tp_size: int, devices=None, axis: str = "model"):
     """1-D ``(axis,)`` mesh over the first ``tp_size`` devices — the
     serving stack's tensor-parallel mesh (``serving/tp.py`` builds its
     TPContext on it; the same ``model`` axis name the param/activation
-    rule sets already target)."""
+    rule sets already target), its axis ``Auto`` (``auto_mesh``): the
+    exact-TP all-gathers are GSPMD's answer to the ``constrain`` hints."""
     if tp_size < 1:
         raise ValueError(f"tp_size must be >= 1, got {tp_size}")
     devices = list(devices) if devices is not None else jax.devices()
@@ -43,7 +54,7 @@ def make_tp_mesh(tp_size: int, devices=None, axis: str = "model"):
         raise ValueError(
             f"tp_size={tp_size} needs {tp_size} devices, "
             f"have {len(devices)}")
-    return jax.make_mesh((tp_size,), (axis,), devices=devices[:tp_size])
+    return auto_mesh((tp_size,), (axis,), devices=devices[:tp_size])
 
 
 def param_rules(mode: str = "tp") -> Dict[str, Any]:

@@ -129,16 +129,19 @@ import random
 import signal
 
 import jax
+import jax.numpy as jnp
 
 from ..core.baselines import spec_decode_reason, vanilla_reason
 from ..core.controller import SpecReason, SpecReasonConfig
 from ..core.policies import StaticThreshold
 from ..data import tasks
 from ..data.evaluate import is_correct
+from ..models.model import Model
 from ..sampling.sample import SamplingParams
 from ..serving.admin import AdminServer, StatusBoard
 from ..serving.compile_watch import (CompileWatch, MemoryWatch,
                                      ProfilerCapture)
+from ..serving.engine import Engine
 from ..serving.faults import FaultInjector, FaultPlan
 from ..serving.kv_manager import KVBudget, KVManager
 from ..serving.loader import load_testbed_engines
@@ -150,6 +153,7 @@ from ..serving.telemetry import (TTFT_BUCKETS, MetricsRegistry,
 from ..serving.workload import (expand_best_of_n, majority_vote,
                                 poisson_arrivals, run_workload, summarize)
 from ..tokenizer import toy as tk
+from .compile_cache import enable_compile_cache
 
 SCHEMES = ("base", "small", "specdecode", "specreason", "specreason+decode")
 
@@ -246,9 +250,11 @@ def sequential_metrics(base, small, latencies, out_tokens: int) -> str:
     return reg.render()
 
 
-def serve_continuous(args, base, small, reqs, fused: bool) -> None:
+def serve_continuous(args, base, small, reqs, fused: bool):
     """Continuous-batching serving path: paged-KV admission + per-tick
-    speculate/verify batching (serving.scheduler.ContinuousScheduler)."""
+    speculate/verify batching (serving.scheduler.ContinuousScheduler).
+    Returns ``(handles, stats)``: every request's handle and the summary
+    dict printed as the run's last JSON line."""
     import time
     cfg = SpecReasonConfig(policy=StaticThreshold(args.threshold),
                            token_budget=args.budget,
@@ -479,9 +485,12 @@ def serve_continuous(args, base, small, reqs, fused: bool) -> None:
                   f"scrapes", flush=True)
             time.sleep(args.admin_linger)
         admin.stop()
+    return handles, stats
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The CLI's argument namespace, validated — what ``main`` serves
+    from, and what a script driving ``serve_continuous`` itself builds."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--scheme", choices=SCHEMES + ("all",),
                     default="specreason")
@@ -698,17 +707,31 @@ def main(argv=None):
                  "add --scheduler continuous")
     if args.vote and args.num_samples < 2:
         ap.error("--vote needs --num-samples >= 2")
+    return args
 
+
+def random_engine_pair(base_cfg, small_cfg, max_len: int = 1024,
+                       fused: bool = True, dtype=jnp.float32):
+    """Base/draft ``Engine`` pair with random weights from seeds 0 and 1
+    — a smoke pair that drives the serving machinery (its answers are
+    nonsense)."""
+    engines = []
+    for seed, cfg in enumerate((base_cfg, small_cfg)):
+        model = Model(cfg)
+        engines.append(Engine(model,
+                              model.init(jax.random.PRNGKey(seed), dtype),
+                              max_len=max_len, name=cfg.name, fused=fused))
+    return engines[0], engines[1]
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
     fused = args.decode_loop == "fused"
     if args.testbed == "micro":
         from ..configs import testbed
-        from ..models.model import Model
-        from ..serving.engine import Engine
-        bm, sm = Model(testbed.MICRO), Model(testbed.MICRO_SMALL)
-        base = Engine(bm, bm.init(jax.random.PRNGKey(0)), max_len=1024,
-                      name="testbed-micro", fused=fused)
-        small = Engine(sm, sm.init(jax.random.PRNGKey(1)), max_len=1024,
-                       name="testbed-micro-small", fused=fused)
+        base, small = random_engine_pair(testbed.MICRO, testbed.MICRO_SMALL,
+                                         fused=fused)
     else:
         base, small = load_testbed_engines(args.ckpt_dir)
     rng = random.Random(args.seed)

@@ -59,14 +59,13 @@ DEFAULT_RULES: Dict[str, Any] = {
 # the data axis (ZeRO-3-like; XLA inserts all-gathers at use sites).
 FSDP_RULES = dict(DEFAULT_RULES, embed="data")
 
-# Exact-TP variant (sharded serving): shard ONLY the output dims of the
-# first GEMM of each pair (q/k/v heads, ffn hidden) and keep every
-# contraction operand replicated — including the unembed, so sampling sees
-# replicated logits.  Combined with models.sharding.exact_tp_activation_rules
-# this makes a TP>1 forward bitwise-identical to TP=1 (the serving
-# equivalence gate, tests/test_tp_serving.py).  Engines must check that
-# tp divides n_heads/n_kv_heads: the head_dim FALLBACK would shard a
-# contraction dim and break exactness.
+# Exact-TP variant (sharded serving): shard q/k/v heads and the ffn
+# hidden, keep the unembed replicated so sampling sees replicated logits.
+# "heads"/"mlp" also shard wo/w_down, on their contracting dims: the
+# compiler then all-reduces those dots, so TP>1 matches TP=1 bitwise only
+# on the toy configs of tests/test_tp_serving.py (DESIGN.md §Sharded
+# serving).  Engines must check that tp divides n_heads/n_kv_heads: the
+# head_dim FALLBACK would shard a contraction dim.
 EXACT_TP_RULES = dict(DEFAULT_RULES, vocab=None, experts=None,
                       ssm_inner=None, ssm_heads=None)
 
@@ -113,9 +112,19 @@ def is_spec(x) -> bool:
 
 def init_params(spec_tree: Pytree, key: jax.Array, dtype=jnp.float32) -> Pytree:
     leaves, treedef = jax.tree.flatten(spec_tree, is_leaf=is_spec)
-    keys = jax.random.split(key, len(leaves))
-    vals = [_materialize(s, k, dtype) for s, k in zip(leaves, keys)]
-    return jax.tree.unflatten(treedef, vals)
+
+    def draw(key):
+        keys = jax.random.split(key, len(leaves))
+        return [_materialize(s, k, dtype) for s, k in zip(leaves, keys)]
+
+    # A narrower dtype is drawn in float32 and cast inside one program, so
+    # no float32 draw is held in device memory (eagerly, phi3's largest
+    # leaf holds 6.4 GB of float32 beside the 1.6 GB it becomes).  Float32
+    # stays eager: fusing changes its bits on XLA:CPU, and the trained
+    # testbed starts from them.
+    if jnp.dtype(dtype) == jnp.float32:
+        return jax.tree.unflatten(treedef, draw(key))
+    return jax.tree.unflatten(treedef, jax.jit(draw)(key))
 
 
 def abstract_params(spec_tree: Pytree, dtype=jnp.bfloat16) -> Pytree:

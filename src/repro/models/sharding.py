@@ -4,7 +4,9 @@ Model code calls ``constrain(x, ("act_batch", None, "act_heads", None))``
 with *logical* activation axes; the launcher installs a mapping from logical
 axes to mesh axes for the mesh/shape at hand.  Outside any context (CPU
 tests, single device) ``constrain`` is a no-op, keeping the model code
-mesh-agnostic.
+mesh-agnostic.  Inside a context the constraint is applied or the call
+raises: a spec the mesh cannot take is an error, never an unconstrained
+array.
 """
 
 from __future__ import annotations
@@ -96,7 +98,4 @@ def constrain(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
         elif m is not None:
             used.add(key)
         spec.append(m)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

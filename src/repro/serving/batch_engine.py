@@ -114,7 +114,11 @@ class BatchEngine:
         # the watch-less engine — same contract as the tracer.
         self.compile_watch = compile_watch
         self._last_cost: Optional[dict] = None
-        state = model.init_state(batch, capacity)
+        # the KV cache takes the params' dtype: bf16 weights get a bf16
+        # cache (what KVManager's 2-byte accounting assumes), the fp32
+        # testbed an fp32 one
+        state = model.init_state(batch, capacity,
+                                 dtype=params["tok_embed"].dtype)
         state = dataclasses.replace(
             state, pos=jnp.zeros((batch,), jnp.int32))
         self.state = state if tp is None else tp.shard_state(state)

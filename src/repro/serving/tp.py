@@ -2,30 +2,18 @@
 exact-TP sharding rules, and the placement helpers every serving layer
 shares.
 
-Design (DESIGN.md §Sharded serving): serving TP must be *bit-exact*
+Design (DESIGN.md §Sharded serving): serving TP aims to be *bit-exact*
 against the single-device path — the scheduler's token-identity
 guarantees (batched vs sequential, spec-decode vs plain decode, cached
-vs uncached prefixes) are all transitive through the engine, so a TP
-mode that only promised tolerance would demote every one of them.
-Exactness comes from sharding ONLY the output (non-contraction) dims of
-each GEMM pair:
-
-  * q/k/v projections sharded over heads / kv-heads ("model" axis);
-    attention itself is per-kv-head — embarrassingly parallel over the
-    axis — and the pre-``out_proj`` gather (``act_out_heads`` -> None)
-    makes the output projection a replicated dot with single-device
-    reduction order;
-  * mlp up/gate sharded over the ffn hidden dim, with the
-    pre-down-projection gather (``act_mlp_hidden`` -> None);
-  * ``wo``/``w_down``/embed/unembed REPLICATED (``EXACT_TP_RULES``), so
-    every contraction — the places where split-axis partial sums would
-    reorder float additions — runs with unsharded operands.
-
-A column slice of a dot preserves the unsharded reduction order and an
-all-gather moves bits without arithmetic, so TP=k logits are bitwise the
-TP=1 logits (probed + enforced by tests/test_tp_serving.py).  The cost
-is an all-gather per GEMM pair instead of Megatron's row-parallel psum —
-the exactness/efficiency trade this stack deliberately makes.
+vs uncached prefixes) are all transitive through the engine.  The rules
+shard q/k/v over heads / kv-heads and the mlp up/gate over the ffn
+hidden dim ("model" axis), keep embed/unembed replicated, and ask for the
+heads and the hidden to be gathered before ``out_proj`` and the
+down-projection (``act_out_heads`` / ``act_mlp_hidden`` -> None).  But
+``EXACT_TP_RULES`` also shard ``wo`` and ``w_down`` on heads / hidden —
+their contracting dims — so the compiler finishes those dots with an
+all-reduce: bitwise on the toy configs of tests/test_tp_serving.py at
+tp=2, float-close (not bitwise) at phi3 widths.
 
 KV layout: the batched decode state (L, B, capacity, kv_heads, hd) and
 every page store shard on the kv-heads dim; block tables, free lists and

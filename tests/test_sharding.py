@@ -8,6 +8,7 @@ out of this process's caches."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -81,6 +82,7 @@ SUBPROCESS_PROG = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.models.config import ModelConfig
     from repro.models.model import Model
+    from repro.launch.mesh import make_test_mesh
     from repro.models.sharding import activation_sharding, \\
         default_activation_rules
 
@@ -94,7 +96,7 @@ SUBPROCESS_PROG = textwrap.dedent("""
 
     ref, _ = model.forward(params, toks)   # single-logical-device
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh((2, 4))
     pspecs = model.partition_specs(mesh_shape=dict(mesh.shape))
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
     rules = default_activation_rules(("data",))
@@ -115,5 +117,25 @@ def test_sharded_forward_matches_single_device(forced_xla_env):
     # restore handled there) — no raw os.environ mutation in the child
     r = subprocess.run([sys.executable, "-c", SUBPROCESS_PROG],
                        capture_output=True, text=True, timeout=600,
-                       env=forced_xla_env, cwd="/root/repo")
+                       env=forced_xla_env,
+                       cwd=Path(__file__).resolve().parents[1])
     assert "SHARDED_OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_constrain_raises_on_unappliable_spec():
+    """Inside a rules context ``constrain`` applies the constraint or
+    raises: a rule naming an axis the mesh does not have is an error, not
+    an unconstrained array.  Outside any context it is the identity."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch.mesh import make_tp_mesh
+    from repro.models.sharding import (activation_sharding, constrain,
+                                       default_activation_rules)
+
+    x = jnp.ones((4, 8))
+    assert constrain(x, ("act_batch", None)) is x
+    rules = default_activation_rules(data_axes=("data",))
+    with make_tp_mesh(2), activation_sharding(rules):
+        with pytest.raises(ValueError, match="data"):
+            jax.jit(lambda a: constrain(a, ("act_batch", None)))(x)

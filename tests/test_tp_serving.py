@@ -28,8 +28,8 @@ from repro.core.controller import SpecReason, SpecReasonConfig
 from repro.core.policies import StaticThreshold
 from repro.data import tasks
 from repro.kernels import ref
-from repro.kernels.paged_tp import (sharded_kernel_supported,
-                                    tp_paged_append_attention,
+from repro.kernels.ops import interpret_mode
+from repro.kernels.paged_tp import (tp_paged_append_attention,
                                     tp_paged_decode_attention)
 from repro.launch.mesh import make_tp_mesh
 from repro.models.config import ModelConfig
@@ -185,8 +185,8 @@ def _decode_case(rng, b=3, h=4, k=2, hd=8, pages=16, nb=3, bs=4):
 
 
 def test_tp_decode_kernel_bitwise_vs_reference():
-    """The sharded decode gather (reference fallback body, the path CPU
-    takes) is BITWISE equal to the unsharded reference: per-shard local
+    """The sharded decode gather (the reference body, asked for
+    explicitly) is BITWISE equal to the unsharded reference: per-shard local
     head slices see whole GQA groups and no cross-head reduction
     exists, so sharding moves no arithmetic."""
     mesh = make_tp_mesh(2)
@@ -228,16 +228,24 @@ def test_tp_decode_kernel_interpret_matches_reference():
     mesh = make_tp_mesh(2)
     q, kp, vp, tbl, lens = _decode_case(np.random.default_rng(2))
     want = ref.paged_decode_reference(q, kp, vp, tbl, lens)
-    got = tp_paged_decode_attention(mesh, q, kp, vp, tbl, lens,
-                                    interpret=True, use_kernel=True)
+    got = tp_paged_decode_attention(mesh, q, kp, vp, tbl, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_sharded_kernel_support_gate():
-    # CPU (this suite) takes the reference fallback; TPU the kernel
-    assert sharded_kernel_supported("tpu")
-    assert not sharded_kernel_supported("cpu")
+    """The backend alone picks compiled vs interpreted kernels; the
+    sharded wrappers run the kernel by default and take the reference
+    body only when asked for (never on tpu)."""
+    assert not interpret_mode("tpu")
+    assert interpret_mode("cpu")
+    mesh = make_tp_mesh(2)
+    q, kp, vp, tbl, lens = _decode_case(np.random.default_rng(3))
+    kernel = tp_paged_decode_attention(mesh, q, kp, vp, tbl, lens)
+    oracle = tp_paged_decode_attention(mesh, q, kp, vp, tbl, lens,
+                                       use_kernel=False)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
 
 
 # ----------------------------------------------------- contract checks
@@ -250,6 +258,14 @@ def test_make_tp_mesh_validates():
         make_tp_mesh(10_000)
     mesh = make_tp_mesh(2)
     assert dict(mesh.shape) == {"model": 2}
+
+
+def test_make_tp_mesh_axis_is_auto():
+    """The TP mesh's axis is Auto (GSPMD propagation from the constrain
+    hints): under jax.make_mesh's Explicit default the exact-TP einsums
+    raise "Contracting dimensions are sharded" instead of gathering."""
+    mesh = make_tp_mesh(2)
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
 
 
 def test_tp_divisibility_contract():
