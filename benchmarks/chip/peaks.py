@@ -1,0 +1,24 @@
+"""Published peaks of the chips the benchmark runs on, by ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s per chip.
+A device that is not in the table is an error: no default stands in.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peak(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add its row to peaks.py with "
+                       f"the source") from None
