@@ -102,8 +102,7 @@ spanned on the ``compile`` tracer track and summarized in the
 ``[compile]`` end-of-run line; post-warmup recompiles feed the
 recompile monitor (bucket churn walks the ``--degrade`` ladder) and a
 device-memory watch samples ``device.memory_stats()`` + model/KV-pool
-byte accounting into gauges and ``/status``.  The live
-FLOP/s-GB/s-intensity join is served at ``/roofline``;
+byte accounting into gauges and ``/status``.
 ``--xla-profile-dir DIR`` additionally arms the admin ``/profile?
 seconds=S`` endpoint (an on-demand ``jax.profiler`` capture into DIR).
 SIGTERM/SIGINT flush the telemetry artifacts before exiting, so an
@@ -351,7 +350,6 @@ def serve_continuous(args, base, small, reqs, fused: bool):
     admin = None
     if admin_on:
         admin = AdminServer(board=board, metrics=metrics, tracer=tracer,
-                            compile_watch=compile_watch,
                             profiler=profiler,
                             port=args.admin_port).start()
         # flush: CI smoke discovers the OS-assigned port from this line
@@ -622,7 +620,7 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "HTTP plane on 127.0.0.1:PORT (0 = OS-assigned, "
                          "printed) — /healthz, /metrics (live Prometheus "
                          "scrape), /status (per-tick scheduler snapshot), "
-                         "/requests/<id>, /trace?last=N, /roofline, and "
+                         "/requests/<id>, /trace?last=N, and "
                          "— with --xla-profile-dir — /profile?seconds=S")
     ap.add_argument("--admin-linger", type=float, default=0.0, metavar="S",
                     help="keep the admin endpoints up S seconds after the "

@@ -254,59 +254,69 @@ def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
     b, _, _ = x.shape
     cap = k_cache.shape[1]
     per_row = jnp.ndim(pos) == 1
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if cfg.use_rope:
-        if per_row:
-            posv = pos.astype(jnp.int32)[:, None]
-        else:
-            posv = jnp.full((b, 1), pos, dtype=jnp.int32)
-        q = apply_rope(q, posv, cfg.rope_theta)
-        k = apply_rope(k, posv, cfg.rope_theta)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+        if cfg.use_rope:
+            if per_row:
+                posv = pos.astype(jnp.int32)[:, None]
+            else:
+                posv = jnp.full((b, 1), pos, dtype=jnp.int32)
+            q = apply_rope(q, posv, cfg.rope_theta)
+            k = apply_rope(k, posv, cfg.rope_theta)
 
     slot = (pos % cap) if ring else jnp.minimum(pos, cap - 1)
-    if per_row:
-        # Vectorized one-hot select instead of a batched scatter: XLA CPU
-        # lowers the scatter to a scalar loop over the whole (B, C, K, hd)
-        # cache (measured ~6x per-token cost at B=8); the select is a
-        # plain vector op over the same buffer.
-        hot = (jnp.arange(cap)[None, :] == slot[:, None])[:, :, None, None]
-        k_cache = jnp.where(hot, k.astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(hot, v.astype(v_cache.dtype), v_cache)
-    else:
-        k_cache = _dyn_write(k_cache, k, slot)
-        v_cache = _dyn_write(v_cache, v, slot)
+    with jax.named_scope("kv_write"):
+        if per_row:
+            # Vectorized one-hot select instead of a batched scatter: XLA
+            # CPU lowers the scatter to a scalar loop over the whole
+            # (B, C, K, hd) cache (measured ~6x per-token cost at B=8);
+            # the select is a plain vector op over the same buffer.
+            hot = (jnp.arange(cap)[None, :]
+                   == slot[:, None])[:, :, None, None]
+            k_cache = jnp.where(hot, k.astype(k_cache.dtype), k_cache)
+            v_cache = jnp.where(hot, v.astype(v_cache.dtype), v_cache)
+        else:
+            k_cache = _dyn_write(k_cache, k, slot)
+            v_cache = _dyn_write(v_cache, v, slot)
 
-    # GQA-grouped flash-decode (the XLA twin of kernels/decode_attention):
-    # no kv-head repetition, no f32 cache copies, and the attention math is
-    # sharded by kv-head groups over the model axis — without the
-    # constraint, a head_dim-sharded cache costs one f32 cache ALL-GATHER
-    # per layer per token (EXPERIMENTS.md §Perf iteration q2).
-    kh, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-    hd = q.shape[-1]
-    qg = q.reshape(b, kh, g, hd)
-    qg = constrain(qg, ("act_batch", "act_kv", None, None))
-    kc = constrain(k_cache, ("act_batch", "act_cache_seq", "act_kv", None))
-    vc = constrain(v_cache, ("act_batch", "act_cache_seq", "act_kv", None))
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, kc,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(hd))
-    # valid entries: linear -> j <= pos (within the sliding window if any);
-    # ring -> every slot written so far (the buffer IS the window)
-    j = jnp.arange(cap).reshape(1, 1, 1, cap)
-    pos_b = pos[:, None, None, None] if per_row else pos
-    if ring:
-        mask = (j < jnp.minimum(pos_b + 1, cap))
-    else:
-        mask = (j <= pos_b)
-        if cfg.sliding_window:
-            mask = mask & (j > pos_b - cfg.sliding_window)
-    scores = jnp.where(mask, scores, NEG_INF)       # (b, kh, g, cap)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(vc.dtype), vc)
-    out = out.reshape(b, 1, cfg.n_heads, hd)
-    return out_proj(out, p), k_cache, v_cache
+    with jax.named_scope("attn"):
+        # GQA-grouped flash-decode (the XLA twin of
+        # kernels/decode_attention): no kv-head repetition, no f32 cache
+        # copies, and the attention math is sharded by kv-head groups over
+        # the model axis — without the constraint, a head_dim-sharded cache
+        # costs one f32 cache ALL-GATHER per layer per token
+        # (EXPERIMENTS.md §Perf iteration q2).
+        kh, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        hd = q.shape[-1]
+        qg = q.reshape(b, kh, g, hd)
+        qg = constrain(qg, ("act_batch", "act_kv", None, None))
+        kc = constrain(k_cache,
+                       ("act_batch", "act_cache_seq", "act_kv", None))
+        vc = constrain(v_cache,
+                       ("act_batch", "act_cache_seq", "act_kv", None))
+        scores = jnp.einsum("bkgd,bskd->bkgs", qg, kc,
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(jnp.float32(hd))
+        # valid entries: linear -> j <= pos (within the sliding window if
+        # any); ring -> every slot written so far (the buffer IS the
+        # window)
+        j = jnp.arange(cap).reshape(1, 1, 1, cap)
+        pos_b = pos[:, None, None, None] if per_row else pos
+        if ring:
+            mask = (j < jnp.minimum(pos_b + 1, cap))
+        else:
+            mask = (j <= pos_b)
+            if cfg.sliding_window:
+                mask = mask & (j > pos_b - cfg.sliding_window)
+        scores = jnp.where(mask, scores, NEG_INF)       # (b, kh, g, cap)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(vc.dtype), vc)
+        out = out.reshape(b, 1, cfg.n_heads, hd)
+        out = out_proj(out, p)
+    return out, k_cache, v_cache
+
 
 
 def _dyn_write(cache: jax.Array, new: jax.Array, slot: jax.Array) -> jax.Array:
@@ -333,53 +343,60 @@ def prefill_self_attention(x: jax.Array, p: Dict[str, jax.Array],
     b, s, _ = x.shape
     cap = k_cache.shape[1]
     per_row = jnp.ndim(start) == 1
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if per_row:
-        posv = (start[:, None] + jnp.arange(s)[None, :]).astype(jnp.int32)
-    else:
-        posv = jnp.broadcast_to(
-            (start + jnp.arange(s))[None, :].astype(jnp.int32), (b, s))
-    if cfg.use_rope:
-        q = apply_rope(q, posv, cfg.rope_theta)
-        k = apply_rope(k, posv, cfg.rope_theta)
-    if per_row:
-        # per-row scatter; trailing-pad writes past a row's real length are
-        # clamped into the last slot, which is harmless for the same reason
-        # trailing pads are (overwritten before it becomes visible) as long
-        # as the caller keeps real contexts below capacity (asserted by the
-        # batch engine).
-        idx = jnp.minimum(posv, cap - 1)
-        rows = jnp.arange(b)[:, None]
-        k_cache = k_cache.at[rows, idx].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, idx].set(v.astype(v_cache.dtype))
-    else:
-        zero = jnp.zeros((), jnp.int32)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype),
-            (zero, start.astype(jnp.int32), zero, zero))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype),
-            (zero, start.astype(jnp.int32), zero, zero))
-    if not per_row and s * cap > _BLOCKWISE_THRESHOLD:
-        # grouped-GQA blockwise path: no kv head repetition in HBM
-        out = blockwise_sdpa(q, k_cache, v_cache, start, causal=True,
-                             window=window)
-    else:
-        n_rep = cfg.n_heads // cfg.n_kv_heads
-        kf = _repeat_kv(k_cache, n_rep)
-        vf = _repeat_kv(v_cache, n_rep)
-        kj = jnp.arange(cap)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
         if per_row:
-            mask = (kj[None, None, :] <= posv[:, :, None])   # (b, s, cap)
-            if window:
-                mask = mask & (kj[None, None, :] > posv[:, :, None] - window)
-            out = sdpa(q, kf, vf, mask[:, None])
+            posv = (start[:, None]
+                    + jnp.arange(s)[None, :]).astype(jnp.int32)
         else:
-            qi = (start + jnp.arange(s))[:, None]
-            mask = (kj[None, :] <= qi)
-            if window:
-                mask = mask & (kj[None, :] > qi - window)
-            out = sdpa(q, kf, vf, mask[None, None])
-    return out_proj(out, p), k_cache, v_cache
+            posv = jnp.broadcast_to(
+                (start + jnp.arange(s))[None, :].astype(jnp.int32), (b, s))
+        if cfg.use_rope:
+            q = apply_rope(q, posv, cfg.rope_theta)
+            k = apply_rope(k, posv, cfg.rope_theta)
+    with jax.named_scope("kv_write"):
+        if per_row:
+            # per-row scatter; trailing-pad writes past a row's real
+            # length are clamped into the last slot, which is harmless for
+            # the same reason trailing pads are (overwritten before it
+            # becomes visible) as long as the caller keeps real contexts
+            # below capacity (asserted by the batch engine).
+            idx = jnp.minimum(posv, cap - 1)
+            rows = jnp.arange(b)[:, None]
+            k_cache = k_cache.at[rows, idx].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[rows, idx].set(v.astype(v_cache.dtype))
+        else:
+            zero = jnp.zeros((), jnp.int32)
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype),
+                (zero, start.astype(jnp.int32), zero, zero))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype),
+                (zero, start.astype(jnp.int32), zero, zero))
+    with jax.named_scope("attn"):
+        if not per_row and s * cap > _BLOCKWISE_THRESHOLD:
+            # grouped-GQA blockwise path: no kv head repetition in HBM
+            out = blockwise_sdpa(q, k_cache, v_cache, start, causal=True,
+                                 window=window)
+        else:
+            n_rep = cfg.n_heads // cfg.n_kv_heads
+            kf = _repeat_kv(k_cache, n_rep)
+            vf = _repeat_kv(v_cache, n_rep)
+            kj = jnp.arange(cap)
+            if per_row:
+                # (b, s, cap)
+                mask = (kj[None, None, :] <= posv[:, :, None])
+                if window:
+                    mask = mask & (kj[None, None, :]
+                                   > posv[:, :, None] - window)
+                out = sdpa(q, kf, vf, mask[:, None])
+            else:
+                qi = (start + jnp.arange(s))[:, None]
+                mask = (kj[None, :] <= qi)
+                if window:
+                    mask = mask & (kj[None, :] > qi - window)
+                out = sdpa(q, kf, vf, mask[None, None])
+        out = out_proj(out, p)
+    return out, k_cache, v_cache

@@ -298,6 +298,11 @@ def _constrain_hidden(h: jax.Array) -> jax.Array:
 
 
 def apply_mlp(x: jax.Array, p: Dict[str, jax.Array], act: str) -> jax.Array:
+    with jax.named_scope("mlp"):
+        return _mlp(x, p, act)
+
+
+def _mlp(x: jax.Array, p: Dict[str, jax.Array], act: str) -> jax.Array:
     if act == "swiglu":
         g = jnp.einsum("...d,df->...f", x, p["w_gate"])
         u = jnp.einsum("...d,df->...f", x, p["w_up"])

@@ -147,11 +147,12 @@ class Model:
         return constrain(x, ("act_batch", "act_seq", "act_embed"))
 
     def _unembed(self, params, x) -> jax.Array:
-        if self.cfg.tie_embeddings:
-            logits = jnp.einsum("...d,vd->...v", x, params["tok_embed"])
-        else:
-            logits = jnp.einsum("...d,dv->...v", x, params["unembed"])
-        return constrain(logits, ("act_batch", "act_seq", "act_vocab"))
+        with jax.named_scope("lm_head"):
+            if self.cfg.tie_embeddings:
+                logits = jnp.einsum("...d,vd->...v", x, params["tok_embed"])
+            else:
+                logits = jnp.einsum("...d,dv->...v", x, params["unembed"])
+            return constrain(logits, ("act_batch", "act_seq", "act_vocab"))
 
     # --------------------------------------------------------- train blocks --
     def _block_train(self, x, lp, positions, extras) -> Tuple[jax.Array, Dict]:

@@ -16,9 +16,6 @@ per-tick state while the run is live:
                              tracer track as a JSON event list)
     GET /trace?last=N     -> Chrome-trace JSON of the last N ring events
                              (full ring without ?last=)
-    GET /roofline         -> live per-op roofline join from the compile
-                             sentinel: compile counts + cost-model
-                             FLOPs/bytes over measured device seconds
     GET /profile?seconds=S-> run a jax.profiler capture for S seconds
                              into the attached profiler's directory and
                              return the artifact path (409 while another
@@ -151,15 +148,13 @@ class _AdminHandler(BaseHTTPRequestHandler):
                 self._route_request(path[len("/requests/"):])
             elif path == "/trace":
                 self._route_trace(url.query)
-            elif path == "/roofline":
-                self._route_roofline()
             elif path == "/profile":
                 self._route_profile(url.query)
             else:
                 self._json(404, {"error": f"no route {path!r}",
                                  "routes": ["/healthz", "/metrics",
                                             "/status", "/requests/<id>",
-                                            "/trace?last=N", "/roofline",
+                                            "/trace?last=N",
                                             "/profile?seconds=S"]})
         except (BrokenPipeError, ConnectionResetError):
             pass  # client went away mid-scrape
@@ -219,15 +214,6 @@ class _AdminHandler(BaseHTTPRequestHandler):
                 return
         self._json(200, tracer.chrome_trace(last=last))
 
-    def _route_roofline(self) -> None:
-        watch = self.server.compile_watch  # type: ignore[attr-defined]
-        if watch is None:
-            self._json(404, {"error": "compile watch not attached "
-                                      "(run with --trace or --metrics-out "
-                                      "to enable the compile sentinel)"})
-            return
-        self._json(200, watch.roofline())
-
     def _route_profile(self, query: str) -> None:
         profiler = self.server.profiler  # type: ignore[attr-defined]
         if profiler is None:
@@ -255,21 +241,20 @@ class _AdminHandler(BaseHTTPRequestHandler):
 
 
 class AdminServer:
-    """Owns the ThreadingHTTPServer + its daemon serve thread.  All
-    three attachments are optional: endpoints whose substrate is absent
+    """Owns the ThreadingHTTPServer + its daemon serve thread.  Every
+    attachment is optional: endpoints whose substrate is absent
     answer 404 with a hint instead of failing to start."""
 
     def __init__(self, board: Optional[StatusBoard] = None,
                  metrics: Any = None, tracer: Any = None,
                  host: str = "127.0.0.1", port: int = 0,
-                 compile_watch: Any = None, profiler: Any = None):
+                 profiler: Any = None):
         self._httpd = ThreadingHTTPServer((host, port), _AdminHandler)
         self._httpd.daemon_threads = True
         # the handler reads these off the server instance
         self._httpd.board = board          # type: ignore[attr-defined]
         self._httpd.metrics = metrics      # type: ignore[attr-defined]
         self._httpd.tracer = tracer        # type: ignore[attr-defined]
-        self._httpd.compile_watch = compile_watch  # type: ignore[attr-defined]
         self._httpd.profiler = profiler    # type: ignore[attr-defined]
         self._thread: Optional[threading.Thread] = None
 

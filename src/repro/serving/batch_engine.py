@@ -46,7 +46,7 @@ import numpy as np
 from ..models.model import Model
 from ..sampling.sample import SamplingParams, probs_from_logits, sample
 from .engine import DEFAULT_BUCKETS, Meter, _STOP_SLOTS
-from .telemetry import Tracer, engine_track
+from .telemetry import NO_REGION, Tracer, engine_track
 from .tp import TPContext
 
 
@@ -55,6 +55,33 @@ class RowSnapshot:
     """O(1) per-row rollback point: position + the logits at it."""
     pos: int
     last_logits: np.ndarray           # (V,) float32
+
+
+def _cache_slice(full_state, cap_eff: int):
+    """The first ``cap_eff`` slots of every row's cache: the attended
+    slice one fused call works on (named ``kv_copy`` on the device)."""
+    if full_state.k is None:
+        return full_state
+    with jax.named_scope("kv_copy"):
+        return dataclasses.replace(full_state,
+                                   k=full_state.k[:, :, :cap_eff],
+                                   v=full_state.v[:, :, :cap_eff])
+
+
+def _cache_merge(full_state, state):
+    """``full_state`` with the worked slice ``state`` merged back at slot
+    0, and ``state``'s positions (``kv_copy``: without donation this
+    rewrites the whole cache)."""
+    if full_state.k is None:
+        return dataclasses.replace(full_state, pos=state.pos)
+    with jax.named_scope("kv_copy"):
+        return dataclasses.replace(
+            full_state,
+            k=jax.lax.dynamic_update_slice(full_state.k, state.k,
+                                           (0, 0, 0, 0, 0)),
+            v=jax.lax.dynamic_update_slice(full_state.v, state.v,
+                                           (0, 0, 0, 0, 0)),
+            pos=state.pos)
 
 
 class BatchEngine:
@@ -76,7 +103,8 @@ class BatchEngine:
                  capacity: int = 1024,
                  buckets: Sequence[int] = DEFAULT_BUCKETS, name: str = "",
                  pad_id: int = 0, tracer: Optional[Tracer] = None,
-                 compile_watch=None, tp: Optional[TPContext] = None):
+                 compile_watch=None, tp: Optional[TPContext] = None,
+                 role: str = "base"):
         if model.cfg.has_ssm:
             raise ValueError(
                 "BatchEngine is attention-only: ragged batched rows rely on "
@@ -98,22 +126,25 @@ class BatchEngine:
         self.capacity = capacity
         self.buckets = tuple(sorted(b for b in buckets if b <= capacity))
         self.name = name or f"batch-{model.cfg.name}"
+        # ``role`` ("base" / "draft") names this engine's tracer regions,
+        # so they read the same whatever model serves the role
+        self.role = role
         self.pad_id = pad_id
         self.meter = Meter()
-        # optional telemetry: engine-call bracket spans on the tracer's
-        # ``engine:<name>`` track; with ``tracer.annotate`` each jitted
-        # dispatch is additionally wrapped in jax.profiler.TraceAnnotation
-        # so device profiles line up with the serving-phase spans.  Every
-        # recording site is guarded on ``tracer is not None`` (the
-        # zero-cost-when-off contract — see serving/telemetry.py).
+        # optional telemetry: one ``<role>.<op>`` region per engine call
+        # on the tracer's ``engine:<name>`` track, tiled by its .put /
+        # .dispatch / .wait / .pull phases (serving/telemetry.py).  Every
+        # region site goes through ``_region``, which returns the shared
+        # no-op context when ``tracer is None`` (the zero-cost-when-off
+        # contract).
         self.tracer = tracer
+        self._track = engine_track(self.name)
         # optional compile sentinel (serving/compile_watch.py): every
         # _dispatch reports its (op, abstract signature) so distinct XLA
         # compilations are counted per op and costed at compile time.
         # None (the default) leaves the dispatch path bit-identical to
         # the watch-less engine — same contract as the tracer.
         self.compile_watch = compile_watch
-        self._last_cost: Optional[dict] = None
         # the KV cache takes the params' dtype: bf16 weights get a bf16
         # cache (what KVManager's 2-byte accounting assumes), the fp32
         # testbed an fp32 one
@@ -123,7 +154,7 @@ class BatchEngine:
             state, pos=jnp.zeros((batch,), jnp.int32))
         self.state = state if tp is None else tp.shard_state(state)
         # static per-token KV footprint (bytes across k+v, all layers) —
-        # the cost annotation on engine-call bracket spans (est. KV bytes
+        # the cost annotation on engine-call spans (est. KV bytes
         # moved); zero for cache-less models
         k = self.state.k
         self._kv_token_bytes = 0 if k is None else (
@@ -221,57 +252,30 @@ class BatchEngine:
         self.state = dataclasses.replace(
             self.state, pos=self._put(self.pos, jnp.int32))
 
+    def _region(self, op: str, phase: str = ""):
+        """The tracer region of one engine call, ``<role>.<op>``, or of
+        one of its phases, ``<role>.<op>.<phase>`` (put / dispatch /
+        wait / pull); the shared no-op context when tracing is off."""
+        tr = self.tracer
+        if tr is None:
+            return NO_REGION
+        name = f"{self.role}.{op}.{phase}" if phase else f"{self.role}.{op}"
+        return tr.region(self._track, name)
+
     def _dispatch(self, op: str, fn: Callable, *args):
-        """Run one jitted engine call, wrapped in a
-        ``jax.profiler.TraceAnnotation`` named ``<engine>.<op>`` when the
-        attached tracer asks for device-profile alignment.  With a
+        """Run one jitted engine call in its ``.dispatch`` region.  With a
         compile watch attached, the call's abstract signature is recorded
-        first (a first-seen signature is a compile event) and its
-        cost-model FLOPs/bytes are held in ``_last_cost`` for the
-        matching ``_bracket`` to stamp onto the parent span.  Under TP
-        the whole body — the watch's lowering twin included — runs inside
-        the mesh + exact-TP activation-rules context, so ``constrain``'s
-        bare PartitionSpecs resolve and tracing matches execution."""
+        first (a first-seen signature is a compile event).  Under TP the
+        whole body — the watch's lowering twin included — runs inside the
+        mesh + exact-TP activation-rules context, so ``constrain``'s bare
+        PartitionSpecs resolve and tracing matches execution."""
         tp_ctx = self.tp.context() if self.tp is not None \
             else contextlib.nullcontext()
-        with tp_ctx:
+        with self._region(op, "dispatch"), tp_ctx:
             cw = self.compile_watch
             if cw is not None:
-                self._last_cost = cw.observe(self.name, op, fn, args)
-            tr = self.tracer
-            if tr is not None and tr.annotate:
-                with jax.profiler.TraceAnnotation(f"{self.name}.{op}"):
-                    return fn(*args)
+                cw.observe(self.name, op, fn, args)
             return fn(*args)
-
-    def _bracket(self, op: str, t0: float, td: float, t1: float,
-                 args: dict) -> None:
-        """Record one engine-call bracket with host/device attribution:
-        the parent span ``<op>`` over [t0, t1) plus two sub-spans —
-        ``<op>.dispatch`` over [t0, td) (host side: argument staging +
-        the jitted call, which returns as soon as the device work is
-        enqueued) and ``<op>.block_until_ready`` over [td, t1) (the wait
-        for device completion — the device-bound window).  Analyzer
-        views must not sum the sub-spans INTO the parent (they tile it);
-        tools/trace_report.py's attribution view excludes them and its
-        hostdev view is built from them.  Caller guards on ``tracer is
-        not None``."""
-        tr = self.tracer
-        track = engine_track(self.name)
-        cw = self.compile_watch
-        if cw is not None:
-            # the measured device window is the live roofline's
-            # denominator; the cost-model numerator rides the parent span
-            cw.note_device(self.name, op, t1 - td)
-            cost = self._last_cost
-            if cost is not None:
-                args = dict(args)
-                args["flops"] = cost.get("flops")
-                args["hlo_bytes"] = cost.get("bytes")
-        tr.span(track, op, t0, t1, args)
-        tr.span(track, f"{op}.dispatch", t0, td, {"side": "host"})
-        tr.span(track, f"{op}.block_until_ready", td, t1,
-                {"side": "device"})
 
     def _prefill_fn(self, cap_eff: int) -> Callable:
         """Batched prefill on a ``cap_eff``-slot cache slice (merged back
@@ -282,23 +286,9 @@ class BatchEngine:
         model = self.model
 
         def prefill(params, tokens, full_state):
-            state = dataclasses.replace(
-                full_state,
-                k=None if full_state.k is None else
-                full_state.k[:, :, :cap_eff],
-                v=None if full_state.v is None else
-                full_state.v[:, :, :cap_eff])
-            logits, state = model.prefill(params, tokens, state)
-            out_state = dataclasses.replace(
-                full_state,
-                k=None if full_state.k is None else
-                jax.lax.dynamic_update_slice(full_state.k, state.k,
-                                             (0, 0, 0, 0, 0)),
-                v=None if full_state.v is None else
-                jax.lax.dynamic_update_slice(full_state.v, state.v,
-                                             (0, 0, 0, 0, 0)),
-                pos=state.pos)
-            return logits, out_state
+            logits, state = model.prefill(params, tokens,
+                                          _cache_slice(full_state, cap_eff))
+            return logits, _cache_merge(full_state, state)
 
         fn = jax.jit(prefill)
         self._prefill_cache[cap_eff] = fn
@@ -320,55 +310,61 @@ class BatchEngine:
         if not rows or max(lens, default=0) == 0:
             return [np.zeros((0, 0), np.float32) for _ in rows] \
                 if want_logits else None
-        bucket = self._bucket(max(lens))
-        for r, n in zip(rows, lens):
-            # the whole padded bucket must fit: pad writes past capacity
-            # would clamp onto the last slot and race the real tail token
-            if self.pos[r] + bucket > self.capacity:
-                raise ValueError(f"row {r} context overflow: "
-                                 f"{self.pos[r]}+{n} (bucket {bucket}) > "
-                                 f"{self.capacity}")
-        toks = np.full((self.batch, bucket), self.pad_id, np.int32)
-        for r, t in zip(rows, token_lists):
-            toks[r, :len(t)] = t
-        # slice width: every live row's whole padded chunk must land
-        # unclamped (uninvolved rows write their pads just past their pos)
-        live = [i for i in range(self.batch) if self._live[i]]
-        need = max(int(self.pos[i]) for i in live) + bucket
-        cap_eff = self._cap_bucket(need)
-        fn = self._prefill_fn(cap_eff)
-        self._sync_pos()
-        t0 = time.perf_counter()
-        logits, new_state = self._dispatch(op, fn, self.params,
-                                           self._put(toks), self.state)
-        td = time.perf_counter()                   # dispatch returned
-        logits = jax.block_until_ready(logits)     # the ONE host sync
-        t1 = time.perf_counter()
-        self.meter.prefill_time += t1 - t0
-        self.meter.prefill_tokens += bucket * len(rows)
-        self.meter.prefill_calls += 1
-        if self.tracer is not None:
-            # est. KV bytes: tokens newly written plus each involved
-            # row's attended prefix window (static annotation, not a
-            # measurement)
-            self._bracket(op, t0, td, t1,
-                          {"rows": len(rows), "tokens": sum(lens),
-                           "bucket": bucket,
-                           "kv_bytes": self._kv_token_bytes
-                           * (sum(lens) + len(rows) * cap_eff)})
-        # per-row position advance: involved rows by their REAL length,
-        # uninvolved rows not at all (their pad chunk wrote past pos only)
-        for r, n in zip(rows, lens):
-            self.pos[r] += n
-        self.state = dataclasses.replace(
-            new_state, pos=jnp.asarray(self.pos, jnp.int32))
-        lg = np.asarray(logits, np.float32)
-        out = []
-        for r, n in zip(rows, lens):
-            if n > 0:
-                self.last_logits[r] = lg[r, n - 1]
-            if want_logits:
-                out.append(lg[r, :n])
+        with self._region(op) as rg:
+            with self._region(op, "put"):
+                bucket = self._bucket(max(lens))
+                for r, n in zip(rows, lens):
+                    # the whole padded bucket must fit: pad writes past
+                    # capacity would clamp onto the last slot and race
+                    # the real tail token
+                    if self.pos[r] + bucket > self.capacity:
+                        raise ValueError(
+                            f"row {r} context overflow: {self.pos[r]}+{n} "
+                            f"(bucket {bucket}) > {self.capacity}")
+                toks = np.full((self.batch, bucket), self.pad_id, np.int32)
+                for r, t in zip(rows, token_lists):
+                    toks[r, :len(t)] = t
+                # slice width: every live row's whole padded chunk must
+                # land unclamped (uninvolved rows write their pads just
+                # past their pos)
+                live = [i for i in range(self.batch) if self._live[i]]
+                need = max(int(self.pos[i]) for i in live) + bucket
+                cap_eff = self._cap_bucket(need)
+                fn = self._prefill_fn(cap_eff)
+                self._sync_pos()
+                t0 = time.perf_counter()
+                toks = self._put(toks)
+            logits, new_state = self._dispatch(op, fn, self.params, toks,
+                                               self.state)
+            with self._region(op, "wait"):
+                logits = jax.block_until_ready(logits)   # the ONE host sync
+            t1 = time.perf_counter()
+            with self._region(op, "pull"):
+                self.meter.prefill_time += t1 - t0
+                self.meter.prefill_tokens += bucket * len(rows)
+                self.meter.prefill_calls += 1
+                # per-row position advance: involved rows by their REAL
+                # length, uninvolved rows not at all (their pad chunk
+                # wrote past pos only)
+                for r, n in zip(rows, lens):
+                    self.pos[r] += n
+                self.state = dataclasses.replace(
+                    new_state, pos=jnp.asarray(self.pos, jnp.int32))
+                lg = np.asarray(logits, np.float32)
+                out = []
+                for r, n in zip(rows, lens):
+                    if n > 0:
+                        self.last_logits[r] = lg[r, n - 1]
+                    if want_logits:
+                        out.append(lg[r, :n])
+            if rg is not None:
+                # est. KV bytes: tokens newly written plus each involved
+                # row's attended prefix window (static annotation, not a
+                # measurement)
+                rg.args.update(rows=len(rows), tokens=sum(lens),
+                               bucket=bucket,
+                               kv_bytes=self._kv_token_bytes
+                               * (sum(lens) + len(rows) * cap_eff))
         return out if want_logits else None
 
     def prefill_rows(self, rows: Sequence[int],
@@ -435,12 +431,7 @@ class BatchEngine:
 
         def fused(params, full_state, last_logits, keys, stop_arr,
                   stop_mask, n_max, greedy_row):
-            state = dataclasses.replace(
-                full_state,
-                k=None if full_state.k is None else
-                full_state.k[:, :, :cap_eff],
-                v=None if full_state.v is None else
-                full_state.v[:, :, :cap_eff])
+            state = _cache_slice(full_state, cap_eff)
             toks0 = jnp.full((batch, buf), -1, jnp.int32)
             vocab = last_logits.shape[-1]
             probs0 = (jnp.zeros((batch, buf, vocab), jnp.float32)
@@ -491,17 +482,7 @@ class BatchEngine:
                     last_logits, keys, toks0, probs0)
             _, _, n, state, logits, _, toks, probs = jax.lax.while_loop(
                 cond, body, init)
-            # merge the decoded slice back into the full-capacity cache
-            out_state = dataclasses.replace(
-                full_state,
-                k=None if full_state.k is None else
-                jax.lax.dynamic_update_slice(full_state.k, state.k,
-                                             (0, 0, 0, 0, 0)),
-                v=None if full_state.v is None else
-                jax.lax.dynamic_update_slice(full_state.v, state.v,
-                                             (0, 0, 0, 0, 0)),
-                pos=state.pos)
-            return toks, n, logits, out_state, probs
+            return toks, n, logits, _cache_merge(full_state, state), probs
 
         fn = jax.jit(fused)
         self._fused_cache[cache_key] = fn
@@ -547,67 +528,72 @@ class BatchEngine:
             return (empty, [np.zeros((0, 0), np.float32) for _ in rows]) \
                 if collect_probs else empty
 
-        buf = self._decode_buf(int(n_max.max()))
-        # attend only the occupied prefix: wide enough for every involved
-        # row's worst-case end AND for every live row's next write slot
-        need = max(max(int(self.pos[i]) + 1 for i in live),
-                   max(int(self.pos[r]) + int(n_max[r]) for r in rows))
-        cap_eff = self._cap_bucket(need)
-        stop = sorted(set(int(s) for s in stop_ids))
-        n_slots = max(_STOP_SLOTS,
-                      -(-len(stop) // _STOP_SLOTS) * _STOP_SLOTS)
-        stop_arr = self._put(stop + [-1] * (n_slots - len(stop)),
-                             jnp.int32)
-        stop_mask = np.zeros((self.batch, n_slots), bool)
-        for i, r in enumerate(rows):
-            allowed = set(int(s) for s in stop_ids_rows[i]) \
-                if stop_ids_rows is not None else set(stop)
-            stop_mask[r] = [s in allowed for s in stop] \
-                + [False] * (n_slots - len(stop))
-        key_mat = np.zeros((self.batch, 2), np.uint32)
-        for r, k in zip(rows, keys):
-            key_mat[r] = np.asarray(k, np.uint32)
-        greedy = np.zeros(self.batch, bool)
-        if greedy_rows is not None:
-            for r, g in zip(rows, greedy_rows):
-                greedy[r] = g
-        fn = self._fused_decode_fn(buf, cap_eff, params, collect_probs)
-
-        self._sync_pos()
-        t0 = time.perf_counter()
-        toks, n, logits, new_state, probs = self._dispatch(
-            "decode", fn,
-            self.params, self.state, self._put(self.last_logits),
-            self._put(key_mat), stop_arr, self._put(stop_mask),
-            self._put(n_max), self._put(greedy))
-        td = time.perf_counter()                        # dispatch returned
-        toks = np.asarray(jax.block_until_ready(toks))  # the ONE host sync
-        n = np.asarray(n)
-        t1 = time.perf_counter()
-        self.meter.decode_time += t1 - t0
-        self.meter.decode_tokens += int(n.sum())
-        self.meter.decode_calls += 1
-        if self.tracer is not None:
-            ntok = int(n.sum())
-            self._bracket("decode", t0, td, t1,
-                          {"rows": len(rows), "tokens": ntok,
-                           "kv_bytes": self._kv_token_bytes
-                           * (ntok + len(rows) * cap_eff)})
-
-        lg = np.asarray(logits, np.float32)
-        out: List[List[int]] = []
-        probs_np = np.asarray(probs, np.float32) if collect_probs else None
-        probs_out: List[np.ndarray] = []
-        for r in rows:
-            k = int(n[r])
-            out.append([int(t) for t in toks[r, :k]])
-            if collect_probs:
-                probs_out.append(probs_np[r, :k])
-            if k > 0:
-                self.pos[r] += k
-                self.last_logits[r] = lg[r]
-        self.state = dataclasses.replace(
-            new_state, pos=jnp.asarray(self.pos, jnp.int32))
+        with self._region("decode") as rg:
+            with self._region("decode", "put"):
+                buf = self._decode_buf(int(n_max.max()))
+                # attend only the occupied prefix: wide enough for every
+                # involved row's worst-case end AND for every live row's
+                # next write slot
+                need = max(max(int(self.pos[i]) + 1 for i in live),
+                           max(int(self.pos[r]) + int(n_max[r])
+                               for r in rows))
+                cap_eff = self._cap_bucket(need)
+                stop = sorted(set(int(s) for s in stop_ids))
+                n_slots = max(_STOP_SLOTS,
+                              -(-len(stop) // _STOP_SLOTS) * _STOP_SLOTS)
+                stop_arr = self._put(stop + [-1] * (n_slots - len(stop)),
+                                     jnp.int32)
+                stop_mask = np.zeros((self.batch, n_slots), bool)
+                for i, r in enumerate(rows):
+                    allowed = set(int(s) for s in stop_ids_rows[i]) \
+                        if stop_ids_rows is not None else set(stop)
+                    stop_mask[r] = [s in allowed for s in stop] \
+                        + [False] * (n_slots - len(stop))
+                key_mat = np.zeros((self.batch, 2), np.uint32)
+                for r, k in zip(rows, keys):
+                    key_mat[r] = np.asarray(k, np.uint32)
+                greedy = np.zeros(self.batch, bool)
+                if greedy_rows is not None:
+                    for r, g in zip(rows, greedy_rows):
+                        greedy[r] = g
+                fn = self._fused_decode_fn(buf, cap_eff, params,
+                                           collect_probs)
+                self._sync_pos()
+                t0 = time.perf_counter()
+                args = (self.params, self.state, self._put(self.last_logits),
+                        self._put(key_mat), stop_arr, self._put(stop_mask),
+                        self._put(n_max), self._put(greedy))
+            toks, n, logits, new_state, probs = self._dispatch(
+                "decode", fn, *args)
+            with self._region("decode", "wait"):
+                toks = jax.block_until_ready(toks)      # the ONE host sync
+            with self._region("decode", "pull"):
+                toks = np.asarray(toks)
+                n = np.asarray(n)
+                t1 = time.perf_counter()
+                self.meter.decode_time += t1 - t0
+                self.meter.decode_tokens += int(n.sum())
+                self.meter.decode_calls += 1
+                lg = np.asarray(logits, np.float32)
+                out: List[List[int]] = []
+                probs_np = np.asarray(probs, np.float32) \
+                    if collect_probs else None
+                probs_out: List[np.ndarray] = []
+                for r in rows:
+                    k = int(n[r])
+                    out.append([int(t) for t in toks[r, :k]])
+                    if collect_probs:
+                        probs_out.append(probs_np[r, :k])
+                    if k > 0:
+                        self.pos[r] += k
+                        self.last_logits[r] = lg[r]
+                self.state = dataclasses.replace(
+                    new_state, pos=jnp.asarray(self.pos, jnp.int32))
+            if rg is not None:
+                ntok = int(n.sum())
+                rg.args.update(rows=len(rows), tokens=ntok,
+                               kv_bytes=self._kv_token_bytes
+                               * (ntok + len(rows) * cap_eff))
         return (out, probs_out) if collect_probs else out
 
     # ------------------------------------------------------ prefix cache
@@ -706,41 +692,30 @@ class BatchEngine:
         bs = k_pages.shape[2]
         max_nb = max(len(s) for s in slot_lists)
         assert max_nb > 0 and all(slot_lists), "empty chain in batched load"
-        slot_mat = np.zeros((len(rows), max_nb), np.int32)
-        for i, (row, slots) in enumerate(zip(rows, slot_lists)):
-            assert self._live[row], f"load into dead row {row}"
-            assert self.pos[row] == 0, \
-                f"load_prefix onto non-fresh row {row} at pos " \
-                f"{self.pos[row]}"
-            assert 0 < len(slots) * bs <= self.capacity
-            slot_mat[i, :len(slots)] = list(slots)
-        fn = self._import_fn((len(rows), max_nb))
-        t0 = time.perf_counter()
-        k, v = self._dispatch("cache_seed", fn,
-                              self.state.k, self.state.v, k_pages, v_pages,
-                              self._put(slot_mat),
-                              self._put(list(rows), jnp.int32))
-        self.state = dataclasses.replace(self.state, k=k, v=v)
-        for row, slots in zip(rows, slot_lists):
-            self.pos[row] = len(slots) * bs
-        if self.tracer is not None:
-            # dispatch-side bracket only: the seed is deliberately not
-            # host-synced (it overlaps the admission tick's later work),
-            # so the whole window is host time — one .dispatch sub-span,
-            # no .block_until_ready
-            td = time.perf_counter()
-            tokens = sum(len(s) * bs for s in slot_lists)
-            track = engine_track(self.name)
-            seed_args = {"rows": len(rows), "tokens": tokens,
-                         "kv_bytes": 2 * tokens * self._kv_token_bytes}
-            cost = self._last_cost if self.compile_watch is not None \
-                else None
-            if cost is not None:
-                seed_args["flops"] = cost.get("flops")
-                seed_args["hlo_bytes"] = cost.get("bytes")
-            self.tracer.span(track, "cache_seed", t0, td, seed_args)
-            self.tracer.span(track, "cache_seed.dispatch", t0, td,
-                             {"side": "host"})
+        # the seed is deliberately not host-synced (it overlaps the
+        # admission tick's later work): no .wait or .pull phase
+        with self._region("cache_seed") as rg:
+            with self._region("cache_seed", "put"):
+                slot_mat = np.zeros((len(rows), max_nb), np.int32)
+                for i, (row, slots) in enumerate(zip(rows, slot_lists)):
+                    assert self._live[row], f"load into dead row {row}"
+                    assert self.pos[row] == 0, \
+                        f"load_prefix onto non-fresh row {row} at pos " \
+                        f"{self.pos[row]}"
+                    assert 0 < len(slots) * bs <= self.capacity
+                    slot_mat[i, :len(slots)] = list(slots)
+                fn = self._import_fn((len(rows), max_nb))
+                args = (self.state.k, self.state.v, k_pages, v_pages,
+                        self._put(slot_mat),
+                        self._put(list(rows), jnp.int32))
+            k, v = self._dispatch("cache_seed", fn, *args)
+            self.state = dataclasses.replace(self.state, k=k, v=v)
+            for row, slots in zip(rows, slot_lists):
+                self.pos[row] = len(slots) * bs
+            if rg is not None:
+                tokens = sum(len(s) * bs for s in slot_lists)
+                rg.args.update(rows=len(rows), tokens=tokens,
+                               kv_bytes=2 * tokens * self._kv_token_bytes)
 
     # -------------------------------------------------------------- feed
     def _feed_fn(self, cap_eff: int) -> Callable:
@@ -753,12 +728,7 @@ class BatchEngine:
         model = self.model
 
         def feed(params, full_state, toks, active):
-            state = dataclasses.replace(
-                full_state,
-                k=None if full_state.k is None else
-                full_state.k[:, :, :cap_eff],
-                v=None if full_state.v is None else
-                full_state.v[:, :, :cap_eff])
+            state = _cache_slice(full_state, cap_eff)
             old_pos = state.pos
             logits, new_state = model.decode_step(params, state,
                                                   toks[:, None])
@@ -766,16 +736,7 @@ class BatchEngine:
             # cache write landed beyond it — masked until overwritten)
             new_state = dataclasses.replace(
                 new_state, pos=jnp.where(active, old_pos + 1, old_pos))
-            out_state = dataclasses.replace(
-                full_state,
-                k=None if full_state.k is None else
-                jax.lax.dynamic_update_slice(full_state.k, new_state.k,
-                                             (0, 0, 0, 0, 0)),
-                v=None if full_state.v is None else
-                jax.lax.dynamic_update_slice(full_state.v, new_state.v,
-                                             (0, 0, 0, 0, 0)),
-                pos=new_state.pos)
-            return logits, out_state
+            return logits, _cache_merge(full_state, new_state)
 
         fn = jax.jit(feed)
         self._feed_cache[cap_eff] = fn
@@ -790,37 +751,39 @@ class BatchEngine:
         assert len(rows) == len(tokens)
         if not rows:
             return
-        live = [i for i in range(self.batch) if self._live[i]]
-        assert all(self.pos[r] < self.capacity for r in rows), \
-            "feed would write past capacity; truncate or preempt first"
-        toks = np.full(self.batch, self.pad_id, np.int32)
-        active = np.zeros(self.batch, bool)
-        for r, t in zip(rows, tokens):
-            toks[r] = t
-            active[r] = True
-        need = max(int(self.pos[i]) for i in live) + 1
-        cap_eff = self._cap_bucket(need)
-        fn = self._feed_fn(cap_eff)
-        self._sync_pos()
-        t0 = time.perf_counter()
-        logits, new_state = self._dispatch("feed", fn,
-                                           self.params, self.state,
-                                           self._put(toks),
-                                           self._put(active))
-        td = time.perf_counter()                   # dispatch returned
-        logits = jax.block_until_ready(logits)     # the ONE host sync
-        t1 = time.perf_counter()
-        self.meter.decode_time += t1 - t0
-        self.meter.decode_tokens += len(rows)
-        self.meter.decode_calls += 1
-        if self.tracer is not None:
-            self._bracket("feed", t0, td, t1,
-                          {"rows": len(rows), "tokens": len(rows),
-                           "kv_bytes": self._kv_token_bytes
-                           * len(rows) * (1 + cap_eff)})
-        lg = np.asarray(logits, np.float32)
-        for r in rows:
-            self.pos[r] += 1
-            self.last_logits[r] = lg[r]
-        self.state = dataclasses.replace(
-            new_state, pos=jnp.asarray(self.pos, jnp.int32))
+        with self._region("feed") as rg:
+            with self._region("feed", "put"):
+                live = [i for i in range(self.batch) if self._live[i]]
+                assert all(self.pos[r] < self.capacity for r in rows), \
+                    "feed would write past capacity; truncate or preempt " \
+                    "first"
+                toks = np.full(self.batch, self.pad_id, np.int32)
+                active = np.zeros(self.batch, bool)
+                for r, t in zip(rows, tokens):
+                    toks[r] = t
+                    active[r] = True
+                need = max(int(self.pos[i]) for i in live) + 1
+                cap_eff = self._cap_bucket(need)
+                fn = self._feed_fn(cap_eff)
+                self._sync_pos()
+                t0 = time.perf_counter()
+                args = (self.params, self.state, self._put(toks),
+                        self._put(active))
+            logits, new_state = self._dispatch("feed", fn, *args)
+            with self._region("feed", "wait"):
+                logits = jax.block_until_ready(logits)   # the ONE host sync
+            t1 = time.perf_counter()
+            with self._region("feed", "pull"):
+                self.meter.decode_time += t1 - t0
+                self.meter.decode_tokens += len(rows)
+                self.meter.decode_calls += 1
+                lg = np.asarray(logits, np.float32)
+                for r in rows:
+                    self.pos[r] += 1
+                    self.last_logits[r] = lg[r]
+                self.state = dataclasses.replace(
+                    new_state, pos=jnp.asarray(self.pos, jnp.int32))
+            if rg is not None:
+                rg.args.update(rows=len(rows), tokens=len(rows),
+                               kv_bytes=self._kv_token_bytes
+                               * len(rows) * (1 + cap_eff))

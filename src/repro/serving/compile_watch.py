@@ -1,6 +1,5 @@
 """Compile- and device-plane observability: the recompilation sentinel,
-live roofline aggregation, device-memory accounting, and on-demand
-profiler capture.
+device-memory accounting, and on-demand profiler capture.
 
 **CompileWatch** (the sentinel) sits at ``BatchEngine._dispatch`` (and
 the one jitted program BatchSpecEngine calls directly,
@@ -28,14 +27,6 @@ is the ground truth.  On a compile event the sentinel:
   bucketed-engine contract, serving/engine.py); sustained signature
   churn after warmup means bucket thrash, which degrading (shrinking
   gamma, capping decode) actively damps.
-
-The per-(engine, op) aggregates (calls, cost-model FLOPs/bytes, and
-measured ``block_until_ready`` device seconds fed back by the engine
-brackets via ``note_device``) are the *live* roofline join — achieved
-GFLOP/s, GB/s, and arithmetic intensity per op — served at the admin
-``/roofline`` endpoint; the offline twin of the same join lives in
-``tools/trace_report.py``'s ``roofline`` view (cost args stamped onto
-the parent engine spans x the ``.block_until_ready`` sub-spans).
 
 **Everything here is observation.**  ``observe`` never raises into the
 dispatch path: a signature it cannot hash or a backend without
@@ -106,13 +97,8 @@ def call_signature(args: Any) -> Tuple[Any, ...]:
     return tuple(out)
 
 
-def _empty_agg() -> Dict[str, Any]:
-    return {"calls": 0, "flops": 0.0, "bytes": 0.0, "device_s": 0.0,
-            "compiles": 0, "post_warmup": 0}
-
-
 class CompileWatch:
-    """Signature-keyed recompilation sentinel + live roofline aggregator.
+    """Signature-keyed recompilation sentinel.
 
     One instance is shared by every engine of a scheduler (the engine
     name disambiguates).  Not thread-safe by design: all observation
@@ -133,7 +119,6 @@ class CompileWatch:
         # (engine, op) -> {signature -> cost dict or None}
         self._sigs: Dict[Tuple[str, str], Dict[Tuple[Any, ...],
                                                Optional[Dict[str, Any]]]] = {}
-        self._agg: Dict[Tuple[str, str], Dict[str, Any]] = {}
         # kept only under keep_hlo=True (tests join vs roofline.hlo_cost)
         self.hlo_text: Dict[Tuple[str, str],
                             Dict[Tuple[Any, ...], str]] = {}
@@ -145,44 +130,26 @@ class CompileWatch:
         observed while ``tick > warmup_ticks`` count as post-warmup."""
         self.tick = int(tick)
 
-    def note_device(self, engine: str, op: str, seconds: float) -> None:
-        """Measured device time (a ``block_until_ready`` sub-span) for
-        one call of (engine, op) — the denominator of the live join."""
-        if seconds > 0.0:
-            agg = self._agg.get((engine, op))
-            if agg is None:
-                agg = self._agg.setdefault((engine, op), _empty_agg())
-            agg["device_s"] += seconds
-
     # -- the sentinel ----------------------------------------------------
 
     def observe(self, engine: str, op: str, fn: Callable,
                 args: Tuple[Any, ...]) -> Optional[Dict[str, Any]]:
         """Record one dispatch of ``fn(*args)`` by (engine, op).  Returns
-        the per-call cost dict (``{"flops", "bytes"}``, values may be
-        None) for the caller to stamp onto its span, or None if the
-        signature could not be hashed.  Never raises."""
+        the signature's cost dict (``{"flops", "bytes"}``, values may be
+        None), or None if the signature could not be hashed.  Never
+        raises."""
         try:
             sig = call_signature(args)
         except Exception:
             return None
-        key = (engine, op)
-        per = self._sigs.setdefault(key, {})
-        agg = self._agg.setdefault(key, _empty_agg())
+        per = self._sigs.setdefault((engine, op), {})
         if sig not in per:
-            per[sig] = self._compile_event(key, sig, fn, args, agg)
-        cost = per[sig]
-        agg["calls"] += 1
-        if cost is not None:
-            if cost.get("flops") is not None:
-                agg["flops"] += cost["flops"]
-            if cost.get("bytes") is not None:
-                agg["bytes"] += cost["bytes"]
-        return cost
+            per[sig] = self._compile_event((engine, op), sig, fn, args)
+        return per[sig]
 
     def _compile_event(self, key: Tuple[str, str], sig: Tuple[Any, ...],
-                       fn: Callable, args: Tuple[Any, ...],
-                       agg: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+                       fn: Callable, args: Tuple[Any, ...]
+                       ) -> Optional[Dict[str, Any]]:
         engine, op = key
         t0 = time.perf_counter()
         flops: Optional[float] = None
@@ -205,10 +172,8 @@ class CompileWatch:
         t1 = time.perf_counter()
         post = self.tick > self.warmup_ticks
         self.compiles += 1
-        agg["compiles"] += 1
         if post:
             self.post_warmup_compiles += 1
-            agg["post_warmup"] += 1
             mon = self.monitors
             if mon is not None:
                 try:
@@ -242,37 +207,6 @@ class CompileWatch:
             "compiles": self.compiles,
             "post_warmup": self.post_warmup_compiles,
         }
-
-    def roofline(self) -> Dict[str, Any]:
-        """The live per-op roofline join for `/roofline`: cost-model
-        FLOPs/bytes (summed over calls) over measured device seconds.
-        Rates are None where no device time was measured (tracing off,
-        or ops that never host-sync, e.g. ``cache_seed``)."""
-        ops = []
-        for (engine, op), agg in sorted(self._agg.items()):
-            dev = agg["device_s"]
-            row = {
-                "engine": engine,
-                "op": op,
-                "calls": agg["calls"],
-                "compiles": agg["compiles"],
-                "post_warmup_compiles": agg["post_warmup"],
-                "flops": agg["flops"],
-                "bytes": agg["bytes"],
-                "device_s": dev,
-                "gflops_per_s": (agg["flops"] / dev / 1e9
-                                 if dev > 0 and agg["flops"] > 0 else None),
-                "gbytes_per_s": (agg["bytes"] / dev / 1e9
-                                 if dev > 0 and agg["bytes"] > 0 else None),
-                "intensity": (agg["flops"] / agg["bytes"]
-                              if agg["bytes"] > 0 else None),
-            }
-            ops.append(row)
-        out = self.as_dict()
-        out["warmup_ticks"] = self.warmup_ticks
-        out["tick"] = self.tick
-        out["ops"] = ops
-        return out
 
     def signatures(self, engine: str, op: str) -> List[Tuple[Any, ...]]:
         """Distinct signatures seen for one op (test hook)."""

@@ -117,8 +117,8 @@ from .resilience import (STATUS_FAILED, STATUS_OK, STATUS_SHED,
                          TickConfig)
 from .spec_engine import BatchSpecEngine, SpecLedger, SpecRow
 from .tp import TPContext
-from .telemetry import (TRACK_SCHED, SchedEvent, ServingMetrics, Tracer,
-                        request_track)
+from .telemetry import (NO_REGION, TRACK_SCHED, SchedEvent, ServingMetrics,
+                        Tracer, request_track)
 
 
 @dataclasses.dataclass
@@ -347,6 +347,8 @@ class _Active:
     # step delimiters, </think> closers) — flushed once per tick in one
     # merged extend
     pending_base: List[int] = dataclasses.field(default_factory=list)
+    # output tokens (thinking + answer) committed so far
+    committed_tokens: int = 0
 
 
 class _SchedulerLedger(SpecLedger):
@@ -516,7 +518,7 @@ class ContinuousScheduler:
                                     name=f"cb-{controller.small.name}",
                                     tracer=tracer,
                                     compile_watch=compile_watch,
-                                    tp=self.tp)
+                                    tp=self.tp, role="draft")
         self.spec_be = BatchSpecEngine(self.base_be, self.small_be,
                                        self.gamma) if self.spec else None
         self.pools = {
@@ -554,6 +556,10 @@ class ContinuousScheduler:
         self.done: List[Request] = []
         self.preemptions = 0
         self.ticks = 0
+        # output tokens (thinking + answer) committed by every request so
+        # far; a preempted request's are taken back, since it recomputes
+        # them
+        self.committed_tokens = 0
         self.prefill_chunks = 0      # chunked-prefill batches dispatched
         # resilience: the overload controller folds per-tick signals into
         # a pressure scalar and walks the degradation ladder; a default
@@ -1036,6 +1042,12 @@ class ContinuousScheduler:
                    request=victim.req.request_id,
                    phase=victim.state.phase, cursor=victim.cursor)
 
+    def _commit(self, a: _Active, n: int) -> None:
+        """Count ``n`` output tokens committed to ``a``'s thinking or
+        answer."""
+        a.committed_tokens += n
+        self.committed_tokens += n
+
     def _release(self, a: _Active) -> None:
         """Release everything an admitted request holds: outstanding
         block-table snapshots, both paged sequences (their own block
@@ -1049,6 +1061,10 @@ class ContinuousScheduler:
         if not a.alive:
             return
         a.alive = False
+        if a.req.status != STATUS_OK:
+            # its tokens leave the output (a requeued request recomputes
+            # them)
+            self.committed_tokens -= a.committed_tokens
         for snap, seq in ((a.b_seq_snap, a.base_seq),
                           (a.s_seq_snap, a.small_seq)):
             if snap is not None:
@@ -1258,93 +1274,125 @@ class ContinuousScheduler:
         current phase as per-phase batched calls.  Returns True while
         there is work left."""
         self.ticks += 1
-        tr, mt = self.tracer, self.metrics
+        tr = self.tracer
         if self.compile_watch is not None:
             # compiles observed from here on belong to this tick (the
             # sentinel's post-warmup window is tick-based)
             self.compile_watch.begin_tick(self.ticks)
-        t_tick0 = time.perf_counter() if tr is not None else 0.0
-        # fault injection first: arm this tick's plan entries (pool holds
-        # claim/release, stall windows open) so the rest of the tick sees
-        # them; a stalled tick skips admission/prefill/phases but still
-        # runs deadline expiry, health scanning and audits — a stalled
-        # engine must never stall the failure lifecycle
-        stalled = False
-        if self.faults is not None:
-            stalled = self.faults.begin_tick(self.ticks, self)
-            if stalled:
-                self.stalled_ticks += 1
-        # failure lifecycle sweeps: expire deadlines (queued AND
-        # mid-flight — cancellation releases blocks/tables/radix refs
-        # idempotently), then shed what can no longer make its SLO
-        self._expire_deadlines()
-        self._shed()
-        # overload controller: fold this tick's signals into pressure and
-        # walk the degradation ladder (hysteresis); the resulting tick
-        # config drives gamma / spec / prefill budget / cache insertion
-        occ = max(p.num_used / p.num_blocks for p in self.pools.values())
-        # row pressure is DEMAND vs capacity (busy rows plus waiting
-        # arrivals), not instantaneous occupancy: this sweep runs before
-        # admission, so a row freed by last tick's finish would read as
-        # idle here even while the queue is about to refill it — the
-        # demand form stays pinned at 1.0 for as long as arrivals
-        # genuinely exceed the row budget
-        busy = self.base_be.batch - min(self.base_be.free_rows,
-                                        self.small_be.free_rows)
-        rows_busy = min(1.0, (busy + len(self.queue)) / self.base_be.batch)
-        # speculation-quality coupling: a firing monitor alarm (evaluated
-        # at the end of the previous tick) raises the pressure floor so
-        # sustained acceptance collapse walks the same ladder occupancy
-        # does — the first rungs (shrink gamma, spec off) are exactly the
-        # remedy for a drafter that has stopped earning its keep
-        mon = self.monitors
-        mon_pressure = mon.pressure() if mon is not None else 0.0
-        for ev in self.res.observe_tick(self.ticks, occ, rows_busy,
-                                        len(self.queue),
-                                        extra_pressure=mon_pressure):
-            # degradation-ladder transitions (either direction), rendered
-            # verbatim — the controller already formats the line
-            self._emit("degrade", ev, tick=self.ticks,
-                       level=self.res.level,
-                       pressure=round(self.res.pressure, 4))
-        tc = self.res.tick_config()
-        spent = 0
-        comp: Dict[str, int] = {}
-        if not stalled:
-            self._admit(key, tc,
-                        quota=self.res.admit_quota(len(self.active)))
-            if tr is not None:
-                # batch composition entering the tick's phase execution
-                for a in self.active:
-                    comp[a.state.phase] = comp.get(a.state.phase, 0) + 1
-            # Stall-free scheduling: the tick's prefill work is bounded
-            # by the tick config's prefill budget (chunked mode), so the
-            # decode/speculation phases below run EVERY tick regardless
-            # of how long the queued prompts are — a long admission
-            # never starves in-flight decodes.
-            spent = self._prefill_tick(tc)
-            # One tick = one reasoning step for every in-flight request:
-            # each phase batch is collected FRESH so a request drafted
-            # this tick is verified this tick (and, on reject,
-            # regenerated this tick) — requests stay phase-synchronized
-            # and every batched call is full.  Call structure per tick:
-            # one small-model fused decode (every drafting request), one
-            # base-model scoring prefill (every verifying request), one
-            # base-model extend (accepted-step delimiters + </think>
-            # closers, deferred and merged), one base-model fused decode
-            # (fallback regenerations + final answers, distinguished by
-            # per-row stop sets), one small-model sync extend.
-            self._phase_acts("speculate", self._speculate_batch)
-            self._phase_acts("verify", self._verify_batch)
-            self._flush_close_batch()
-            fall = self._guard("fallback",
-                               [a for a in self.active
-                                if a.state.phase == "fallback"])
-            ans = self._guard("answer",
-                              [a for a in self.active
-                               if a.state.phase == "answer"])
-            if fall or ans:
-                self._base_decode_batch(fall, ans, tc)
+        with self._region("sched.tick") as rg_tick:
+            with self._region("sched.admit"):
+                # fault injection first: arm this tick's plan entries
+                # (pool holds claim/release, stall windows open) so the
+                # rest of the tick sees them; a stalled tick skips
+                # admission/prefill/phases but still runs deadline
+                # expiry, health scanning and audits — a stalled engine
+                # must never stall the failure lifecycle
+                stalled = False
+                if self.faults is not None:
+                    stalled = self.faults.begin_tick(self.ticks, self)
+                    if stalled:
+                        self.stalled_ticks += 1
+                # failure lifecycle sweeps: expire deadlines (queued AND
+                # mid-flight — cancellation releases blocks/tables/radix
+                # refs idempotently), then shed what can no longer make
+                # its SLO
+                self._expire_deadlines()
+                self._shed()
+                # overload controller: fold this tick's signals into
+                # pressure and walk the degradation ladder (hysteresis);
+                # the resulting tick config drives gamma / spec / prefill
+                # budget / cache insertion
+                occ = max(p.num_used / p.num_blocks
+                          for p in self.pools.values())
+                # row pressure is DEMAND vs capacity (busy rows plus
+                # waiting arrivals), not instantaneous occupancy: this
+                # sweep runs before admission, so a row freed by last
+                # tick's finish would read as idle here even while the
+                # queue is about to refill it — the demand form stays
+                # pinned at 1.0 for as long as arrivals genuinely exceed
+                # the row budget
+                busy = self.base_be.batch - min(self.base_be.free_rows,
+                                                self.small_be.free_rows)
+                rows_busy = min(1.0, (busy + len(self.queue))
+                                / self.base_be.batch)
+                # speculation-quality coupling: a firing monitor alarm
+                # (evaluated at the end of the previous tick) raises the
+                # pressure floor so sustained acceptance collapse walks
+                # the same ladder occupancy does — the first rungs
+                # (shrink gamma, spec off) are exactly the remedy for a
+                # drafter that has stopped earning its keep
+                mon = self.monitors
+                mon_pressure = mon.pressure() if mon is not None else 0.0
+                for ev in self.res.observe_tick(self.ticks, occ, rows_busy,
+                                                len(self.queue),
+                                                extra_pressure=mon_pressure):
+                    # degradation-ladder transitions (either direction),
+                    # rendered verbatim — the controller already formats
+                    # the line
+                    self._emit("degrade", ev, tick=self.ticks,
+                               level=self.res.level,
+                               pressure=round(self.res.pressure, 4))
+                tc = self.res.tick_config()
+                if not stalled:
+                    self._admit(key, tc,
+                                quota=self.res.admit_quota(len(self.active)))
+            spent = 0
+            comp: Dict[str, int] = {}
+            if not stalled:
+                if tr is not None:
+                    # batch composition entering the tick's phase execution
+                    for a in self.active:
+                        comp[a.state.phase] = comp.get(a.state.phase, 0) + 1
+                # Stall-free scheduling: the tick's prefill work is
+                # bounded by the tick config's prefill budget (chunked
+                # mode), so the decode/speculation phases below run EVERY
+                # tick regardless of how long the queued prompts are — a
+                # long admission never starves in-flight decodes.
+                with self._region("sched.prefill"):
+                    spent = self._prefill_tick(tc)
+                # One tick = one reasoning step for every in-flight
+                # request: each phase batch is collected FRESH so a
+                # request drafted this tick is verified this tick (and, on
+                # reject, regenerated this tick) — requests stay
+                # phase-synchronized and every batched call is full.  Call
+                # structure per tick: one small-model fused decode (every
+                # drafting request), one base-model scoring prefill (every
+                # verifying request), one base-model extend (accepted-step
+                # delimiters + </think> closers, deferred and merged), one
+                # base-model fused decode (fallback regenerations + final
+                # answers, distinguished by per-row stop sets), one
+                # small-model sync extend.
+                with self._region("sched.speculate"):
+                    self._phase_acts("speculate", self._speculate_batch)
+                with self._region("sched.verify"):
+                    self._phase_acts("verify", self._verify_batch)
+                with self._region("sched.close"):
+                    self._flush_close_batch()
+                with self._region("sched.decode"):
+                    fall = self._guard("fallback",
+                                       [a for a in self.active
+                                        if a.state.phase == "fallback"])
+                    ans = self._guard("answer",
+                                      [a for a in self.active
+                                       if a.state.phase == "answer"])
+                    if fall or ans:
+                        self._base_decode_batch(fall, ans, tc)
+            with self._region("sched.finish"):
+                working = self._end_tick()
+            if rg_tick is not None:
+                rg_tick.args.update(
+                    tick=self.ticks, queue=len(self.queue),
+                    active=len(self.active), batch=comp,
+                    occupancy=round(occ, 4),
+                    pressure=round(self.res.pressure, 4),
+                    level=self.res.level, prefill_tokens=spent)
+        return working
+
+    def _end_tick(self) -> bool:
+        """The tick's closing sweep: health scan, TTFT stamps, finishing,
+        audits, monitors, memory, metrics, counters and the admin
+        snapshot.  Returns True while there is work left."""
+        tr, mt = self.tracer, self.metrics
         # engine-health guard: injected NaN poisoning lands here
         # (simulating this tick's engine step having corrupted a row),
         # then the scan quarantines every non-finite row BEFORE finish
@@ -1363,6 +1411,7 @@ class ContinuousScheduler:
         self._finish()
         if self.audit_enabled:
             self._audit()
+        mon = self.monitors
         if mon is not None:
             # roll the per-tick windows, evaluate every alarm; alarm
             # transitions flow through the standard event funnel
@@ -1381,22 +1430,16 @@ class ContinuousScheduler:
             for w, p in self.pools.items():
                 mt.pool_occupancy.set(p.num_used / p.num_blocks, pool=w)
         if tr is not None:
-            t_tick1 = time.perf_counter()
-            tr.span(TRACK_SCHED, "tick", t_tick0, t_tick1, {
-                "tick": self.ticks, "queue": len(self.queue),
-                "active": len(self.active), "batch": comp,
-                "occupancy": round(occ, 4),
-                "pressure": round(self.res.pressure, 4),
-                "level": self.res.level, "prefill_tokens": spent})
+            t = time.perf_counter()
             tr.counter("kv_occupancy",
                        {w: round(p.num_used / p.num_blocks, 4)
-                        for w, p in self.pools.items()}, t=t_tick1)
+                        for w, p in self.pools.items()}, t=t)
             tr.counter("pressure",
                        {"pressure": round(self.res.pressure, 4),
-                        "level": float(self.res.level)}, t=t_tick1)
+                        "level": float(self.res.level)}, t=t)
             tr.counter("queue_depth",
                        {"queued": float(len(self.queue)),
-                        "active": float(len(self.active))}, t=t_tick1)
+                        "active": float(len(self.active))}, t=t)
             if self.last_memory is not None:
                 mem_vals = {"accounted":
                             float(self.last_memory["accounted_bytes"]),
@@ -1404,7 +1447,7 @@ class ContinuousScheduler:
                 if self.last_memory["device_bytes_in_use"] is not None:
                     mem_vals["device_in_use"] = float(
                         self.last_memory["device_bytes_in_use"])
-                tr.counter("memory_bytes", mem_vals, t=t_tick1)
+                tr.counter("memory_bytes", mem_vals, t=t)
         if self.status_board is not None or self.on_tick is not None:
             # admin plane: publish one immutable snapshot per tick (the
             # lock is held only for the reference swap) and fire the
@@ -1421,6 +1464,12 @@ class ContinuousScheduler:
             # regardless of where the fault plan ended
             self.faults.release_all(self)
         return working
+
+    def _region(self, name: str):
+        """A tracer region on the scheduler track; the shared no-op
+        context when tracing is off."""
+        tr = self.tracer
+        return NO_REGION if tr is None else tr.region(TRACK_SCHED, name)
 
     def _phase_acts(self, phase: str, fn) -> None:
         acts = self._guard(phase, [a for a in self.active
@@ -1567,6 +1616,7 @@ class ContinuousScheduler:
             verdict, utility = ctrl.judge_draft(utility, a.mean_lp)
             if verdict.accept:
                 delim = ctrl.note_accept(a.state, a.body, a.end, utility)
+                self._commit(a, len(a.body) + 1)
                 a.base_seq.discard_snapshot(a.b_seq_snap)
                 a.small_seq.discard_snapshot(a.s_seq_snap)
                 a.b_seq_snap = a.s_seq_snap = None
@@ -1692,12 +1742,14 @@ class ContinuousScheduler:
         for i, a in enumerate(fall):
             if a.alive and outs[i] is not None:
                 ctrl.note_base_step(a.state, outs[i])
+                self._commit(a, len(outs[i]))
                 if mon is not None:
                     mon.observe_step("fallback")
         for i, a in enumerate(ans):
             ids = outs[len(fall) + i]
             if a.alive and ids is not None:
                 a.state.answer_ids = ids
+                self._commit(a, len(ids))
                 a.state.phase = "done"
         if tr is not None:
             t_dec1 = time.perf_counter()
@@ -1722,6 +1774,7 @@ class ContinuousScheduler:
             if a.state.phase == "close":
                 if not a.state.done_thinking:
                     a.state.thinking += [tk.THINK_END]
+                    self._commit(a, 1)
                     a.pending_base.append(tk.THINK_END)
                 a.state.phase = "answer"
             if a.pending_base:
@@ -1756,6 +1809,7 @@ class ContinuousScheduler:
             "status": a.req.status,
             "priority": a.req.priority,
             "steps": len(a.state.steps),
+            "tokens": a.committed_tokens,
         } for a in self.active if a.alive]
         return SchedulerSnapshot(
             tick=self.ticks,
@@ -1777,6 +1831,7 @@ class ContinuousScheduler:
                 "audit_violations": self.audit_violations,
                 "done": len(self.done),
                 "submitted": self._submitted,
+                "committed_tokens": self.committed_tokens,
             },
             monitors=self.monitors.as_dict()
             if self.monitors is not None else None,

@@ -54,7 +54,7 @@ from ..core.spec_decode import (SpecDecodeStats, acceptance_step,
                                 build_stop_arrays)
 from ..sampling.sample import SamplingParams
 from .batch_engine import BatchEngine
-from .telemetry import engine_track
+from .telemetry import NO_REGION, TRACK_SPEC
 
 
 @dataclasses.dataclass
@@ -169,8 +169,6 @@ class BatchSpecEngine:
         pending: List[Optional[int]] = [None] * n
         stop_arr, stop_mask_items = build_stop_arrays(
             [it.stop_ids for it in items])
-        big = self.base_be.batch
-        vocab = self.base_be.model.cfg.vocab_size
         gam = self.gamma if gamma is None else gamma
         if gam < 1:
             raise ValueError("gamma must be >= 1")
@@ -189,160 +187,145 @@ class BatchSpecEngine:
                     pending[i] is not None and ledger.alive(i)
                     for i in range(n)):
                 break
-            g_want = {i: min(gam, items[i].budget - len(out[i]))
-                      for i in active}
+            with self._region("spec.round"):
+                g_want = {i: min(gam, items[i].budget - len(out[i]))
+                          for i in active}
 
-            # -- 1) one fused draft proposal for every active row
-            b_snap = {i: int(self.base_be.pos[items[i].base_row])
-                      for i in active}
-            d_snap = {i: int(self.draft_be.pos[items[i].draft_row])
-                      for i in active}
-            if active:
-                douts, dprobs = self.draft_be.generate_rows(
-                    [items[i].draft_row for i in active],
-                    [g_want[i] for i in active], [], params,
-                    keys=[jnp.asarray(keys[i]) for i in active],
-                    greedy_rows=[items[i].greedy for i in active],
-                    stop_ids_rows=[[] for _ in active], collect_probs=True)
-            else:
-                douts, dprobs = [], []
-            # no separate key-advance dispatch: acceptance_step performs
-            # the post-draft split internally from the same keys
-            chunks = {i: ids for i, ids in zip(active, douts)}
-            probs = {i: p for i, p in zip(active, dprobs)}
-            for i in active:
-                if not chunks[i]:
-                    done[i] = True        # capacity exhausted: stop clean
-                else:
-                    ledger.grow(i, "draft", len(chunks[i]))
-            verify = [i for i in active if chunks[i] and ledger.alive(i)]
-
-            if verify:
-                # -- 2) one base verification prefill: [pending] + chunk
-                # per row (the pending token's decode rides the prefill)
-                prev = {i:
-                        self.base_be.last_logits[items[i].base_row].copy()
-                        for i in verify if pending[i] is None}
-                ext = {i: ([pending[i]] if pending[i] is not None else [])
-                       + chunks[i] for i in verify}
-                all_l = self.base_be.extend_rows(
-                    [items[i].base_row for i in verify],
-                    [ext[i] for i in verify], want_logits=True)
-                chunk_l = {i: lg for i, lg in zip(verify, all_l)}
-                for i in verify:
-                    ledger.grow(i, "base", len(ext[i]))
-            judge = [i for i in verify if ledger.alive(i)]
-
-            if judge:
-                # -- 3) the fused batched acceptance program (item i at
-                # slot i)
-                toks = np.zeros((big, gam), np.int32)
-                qprobs = np.zeros((big, gam, vocab), np.float32)
-                logits = np.zeros((big, gam, vocab), np.float32)
-                bonus = np.zeros((big, vocab), np.float32)
-                g_arr = np.zeros(big, np.int32)
-                key_mat = np.zeros((big, 2), np.uint32)
-                greedy = np.zeros(big, bool)
-                stop_mask = np.zeros((big, stop_arr.shape[0]), bool)
-                for i in judge:
-                    ga = len(chunks[i])
-                    p = 1 if pending[i] is not None else 0
-                    toks[i, :ga] = chunks[i]
-                    qprobs[i, :ga] = probs[i]
-                    if p:
-                        logits[i, :ga] = chunk_l[i][:ga]
+                # -- 1) one fused draft proposal for every active row
+                with self._region("spec.draft"):
+                    b_snap = {i: int(self.base_be.pos[items[i].base_row])
+                              for i in active}
+                    d_snap = {i: int(self.draft_be.pos[items[i].draft_row])
+                              for i in active}
+                    if active:
+                        douts, dprobs = self.draft_be.generate_rows(
+                            [items[i].draft_row for i in active],
+                            [g_want[i] for i in active], [], params,
+                            keys=[jnp.asarray(keys[i]) for i in active],
+                            greedy_rows=[items[i].greedy for i in active],
+                            stop_ids_rows=[[] for _ in active],
+                            collect_probs=True)
                     else:
-                        logits[i, 0] = prev[i]
-                        if ga > 1:
-                            logits[i, 1:ga] = chunk_l[i][:ga - 1]
-                    bonus[i] = chunk_l[i][p + ga - 1]
-                    g_arr[i] = ga
-                    key_mat[i] = keys[i]
-                    greedy[i] = items[i].greedy
-                    stop_mask[i] = stop_mask_items[i]
-                tr = self.base_be.tracer
-                cw = self.base_be.compile_watch
-                acc_args = (jnp.asarray(toks), jnp.asarray(qprobs),
+                        douts, dprobs = [], []
+                    # no separate key-advance dispatch: acceptance_step
+                    # performs the post-draft split internally from the
+                    # same keys
+                    chunks = {i: ids for i, ids in zip(active, douts)}
+                    probs = {i: p for i, p in zip(active, dprobs)}
+                    for i in active:
+                        if not chunks[i]:
+                            done[i] = True   # capacity exhausted: stop clean
+                        else:
+                            ledger.grow(i, "draft", len(chunks[i]))
+                verify = [i for i in active if chunks[i] and ledger.alive(i)]
+
+                if verify:
+                    # -- 2) one base verification prefill: [pending] +
+                    # chunk per row (the pending token's decode rides the
+                    # prefill)
+                    with self._region("spec.verify"):
+                        prev = {i: self.base_be.last_logits[
+                                    items[i].base_row].copy()
+                                for i in verify if pending[i] is None}
+                        ext = {i: ([pending[i]] if pending[i] is not None
+                                   else []) + chunks[i] for i in verify}
+                        all_l = self.base_be.extend_rows(
+                            [items[i].base_row for i in verify],
+                            [ext[i] for i in verify], want_logits=True)
+                        chunk_l = {i: lg for i, lg in zip(verify, all_l)}
+                        for i in verify:
+                            ledger.grow(i, "base", len(ext[i]))
+                judge = [i for i in verify if ledger.alive(i)]
+
+                if judge:
+                    # -- 3) the fused batched acceptance program (item i
+                    # at slot i)
+                    with self._region("spec.stage"):
+                        big = self.base_be.batch
+                        vocab = self.base_be.model.cfg.vocab_size
+                        toks = np.zeros((big, gam), np.int32)
+                        qprobs = np.zeros((big, gam, vocab), np.float32)
+                        logits = np.zeros((big, gam, vocab), np.float32)
+                        bonus = np.zeros((big, vocab), np.float32)
+                        g_arr = np.zeros(big, np.int32)
+                        key_mat = np.zeros((big, 2), np.uint32)
+                        greedy = np.zeros(big, bool)
+                        stop_mask = np.zeros((big, stop_arr.shape[0]), bool)
+                        for i in judge:
+                            ga = len(chunks[i])
+                            p = 1 if pending[i] is not None else 0
+                            toks[i, :ga] = chunks[i]
+                            qprobs[i, :ga] = probs[i]
+                            if p:
+                                logits[i, :ga] = chunk_l[i][:ga]
+                            else:
+                                logits[i, 0] = prev[i]
+                                if ga > 1:
+                                    logits[i, 1:ga] = chunk_l[i][:ga - 1]
+                            bonus[i] = chunk_l[i][p + ga - 1]
+                            g_arr[i] = ga
+                            key_mat[i] = keys[i]
+                            greedy[i] = items[i].greedy
+                            stop_mask[i] = stop_mask_items[i]
+                        acc_args = (
+                            jnp.asarray(toks), jnp.asarray(qprobs),
                             jnp.asarray(logits), jnp.asarray(bonus),
                             jnp.asarray(g_arr), jnp.asarray(key_mat),
                             jnp.asarray(stop_arr), jnp.asarray(stop_mask),
                             jnp.asarray(greedy), params)
-                # the one jitted program this engine calls directly: the
-                # compile sentinel covers it the same way the BatchEngine
-                # dispatches are covered
-                cost = cw.observe(self.base_be.name, "accept_prog",
-                                  acceptance_step, acc_args) \
-                    if cw is not None else None
-                t_a0 = time.perf_counter() if tr is not None else 0.0
-                suffix, m, n_acc, hit_stop, new_keys = acceptance_step(
-                    *acc_args)
-                t_ad = time.perf_counter() if tr is not None else 0.0
-                suffix = np.asarray(suffix)       # the host sync: the
-                m = np.asarray(m)                 # reconcile below needs
-                n_acc = np.asarray(n_acc)         # the verdicts on host
-                hit_stop = np.asarray(hit_stop)
-                new_keys = np.asarray(new_keys)
-                if tr is not None:
-                    # host/device bracket for the fused acceptance
-                    # program (same sub-span semantics as the
-                    # BatchEngine brackets: .dispatch = staging + jitted
-                    # call, .block_until_ready = the np.asarray wait)
-                    t_a1 = time.perf_counter()
-                    track = engine_track(self.base_be.name)
-                    args = {"rows": len(judge), "gamma": gam}
-                    if cost is not None:
-                        args["flops"] = cost.get("flops")
-                        args["hlo_bytes"] = cost.get("bytes")
-                    if cw is not None:
-                        cw.note_device(self.base_be.name, "accept_prog",
-                                       t_a1 - t_ad)
-                    tr.span(track, "accept_prog", t_a0, t_a1, args)
-                    tr.span(track, "accept_prog.dispatch", t_a0, t_ad,
-                            {"side": "host"})
-                    tr.span(track, "accept_prog.block_until_ready",
-                            t_ad, t_a1, {"side": "device"})
+                    with self._region("spec.accept") as rg:
+                        suffix, m, n_acc, hit_stop, new_keys = \
+                            self._accept(acc_args)
+                        if rg is not None:
+                            rg.args.update(rows=len(judge), gamma=gam)
 
-                # -- 4) reconcile: O(1) truncate + block-table truncation.
-                # The base cache holds [pending] + chunk at the speculated
-                # positions and sfx[:-1] is a prefix of the chunk — keep
-                # p + m - 1 tokens, the new final suffix token becomes the
-                # pending one.  The draft context reconciles eagerly (ONE
-                # batched feed): the next proposal conditions on it.
-                dfeed: List[Tuple[int, int]] = []     # (item, token)
-                for i in judge:
-                    if not ledger.alive(i):
-                        # an earlier row's grow preempted this one: its
-                        # engine rows are freed — do not touch them
-                        continue
-                    ga, mi = len(chunks[i]), int(m[i])
-                    p = 1 if pending[i] is not None else 0
-                    sfx = [int(t) for t in suffix[i, :mi]]
-                    out[i] += sfx
-                    keys[i] = new_keys[i]
-                    stats[i].proposed += ga
-                    stats[i].accepted += int(n_acc[i])
-                    stats[i].rounds += 1
-                    if on_round is not None:
-                        round_info.append((i, ga, int(n_acc[i])))
-                    self.base_be.meter.spec_rounds += 1
-                    self.base_be.meter.spec_proposed += ga
-                    self.base_be.meter.spec_accepted += int(n_acc[i])
-                    new_pos = b_snap[i] + p + mi - 1
-                    self.base_be.truncate_row(items[i].base_row, new_pos)
-                    ledger.truncate(i, "base", new_pos)
-                    pending[i] = sfx[-1]
-                    self.draft_be.truncate_row(items[i].draft_row,
-                                               d_snap[i] + mi - 1)
-                    ledger.truncate(i, "draft", d_snap[i] + mi - 1)
-                    ledger.grow(i, "draft", 1)
-                    if bool(hit_stop[i]) or len(out[i]) >= items[i].budget:
-                        done[i] = True
-                    dfeed.append((i, sfx[-1]))
-                dfeed = [(i, t) for i, t in dfeed if ledger.alive(i)]
-                if dfeed:
-                    self.draft_be.feed_rows(
-                        [items[i].draft_row for i, _ in dfeed],
-                        [t for _, t in dfeed])
+                    # -- 4) reconcile: O(1) truncate + block-table
+                    # truncation.  The base cache holds [pending] + chunk
+                    # at the speculated positions and sfx[:-1] is a
+                    # prefix of the chunk — keep p + m - 1 tokens, the new
+                    # final suffix token becomes the pending one.  The
+                    # draft context reconciles eagerly (ONE batched
+                    # feed): the next proposal conditions on it.
+                    with self._region("spec.reconcile"):
+                        dfeed: List[Tuple[int, int]] = []   # (item, token)
+                        for i in judge:
+                            if not ledger.alive(i):
+                                # an earlier row's grow preempted this
+                                # one: its engine rows are freed — do not
+                                # touch them
+                                continue
+                            ga, mi = len(chunks[i]), int(m[i])
+                            p = 1 if pending[i] is not None else 0
+                            sfx = [int(t) for t in suffix[i, :mi]]
+                            out[i] += sfx
+                            keys[i] = new_keys[i]
+                            stats[i].proposed += ga
+                            stats[i].accepted += int(n_acc[i])
+                            stats[i].rounds += 1
+                            if on_round is not None:
+                                round_info.append((i, ga, int(n_acc[i])))
+                            meter = self.base_be.meter
+                            meter.spec_rounds += 1
+                            meter.spec_proposed += ga
+                            meter.spec_accepted += int(n_acc[i])
+                            new_pos = b_snap[i] + p + mi - 1
+                            self.base_be.truncate_row(items[i].base_row,
+                                                      new_pos)
+                            ledger.truncate(i, "base", new_pos)
+                            pending[i] = sfx[-1]
+                            self.draft_be.truncate_row(items[i].draft_row,
+                                                       d_snap[i] + mi - 1)
+                            ledger.truncate(i, "draft", d_snap[i] + mi - 1)
+                            ledger.grow(i, "draft", 1)
+                            if bool(hit_stop[i]) \
+                                    or len(out[i]) >= items[i].budget:
+                                done[i] = True
+                            dfeed.append((i, sfx[-1]))
+                        dfeed = [(i, t) for i, t in dfeed if ledger.alive(i)]
+                        if dfeed:
+                            self.draft_be.feed_rows(
+                                [items[i].draft_row for i, _ in dfeed],
+                                [t for _, t in dfeed])
 
             # -- 5) finish-feed: rows that just finished commit their
             # pending token with ONE batched base decode (refreshing the
@@ -350,17 +333,43 @@ class BatchSpecEngine:
             fin = [i for i in range(n)
                    if done[i] and pending[i] is not None
                    and ledger.alive(i)]
-            for i in fin:
-                ledger.grow(i, "base", 1)
-            fin = [i for i in fin if ledger.alive(i)]
             if fin:
-                self.base_be.feed_rows(
-                    [items[i].base_row for i in fin],
-                    [pending[i] for i in fin])
-                for i in fin:
-                    pending[i] = None
+                with self._region("spec.finish_feed"):
+                    for i in fin:
+                        ledger.grow(i, "base", 1)
+                    fin = [i for i in fin if ledger.alive(i)]
+                    if fin:
+                        self.base_be.feed_rows(
+                            [items[i].base_row for i in fin],
+                            [pending[i] for i in fin])
+                        for i in fin:
+                            pending[i] = None
             if on_round is not None and round_info:
                 on_round(rounds, t_round0, time.perf_counter(),
                          round_info)
             rounds += 1
         return out, stats
+
+    def _region(self, name: str):
+        """A tracer region on the ``spec`` track; the shared no-op
+        context when tracing is off."""
+        tr = self.base_be.tracer
+        return NO_REGION if tr is None else tr.region(TRACK_SPEC, name)
+
+    def _accept(self, acc_args: tuple) -> List[np.ndarray]:
+        """Run the acceptance program on staged inputs and bring its
+        verdicts to the host (the reconcile needs them there): suffix,
+        m, n_accepted, hit_stop and the advanced keys."""
+        with self._region("spec.accept.dispatch"):
+            # the one jitted program this engine calls directly: the
+            # compile sentinel covers it the same way the BatchEngine
+            # dispatches are covered
+            cw = self.base_be.compile_watch
+            if cw is not None:
+                cw.observe(self.base_be.name, "accept_prog",
+                           acceptance_step, acc_args)
+            res = acceptance_step(*acc_args)
+        with self._region("spec.accept.wait"):
+            res = jax.block_until_ready(res)
+        with self._region("spec.accept.pull"):
+            return [np.asarray(x) for x in res]

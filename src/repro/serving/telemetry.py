@@ -2,37 +2,60 @@
 
 Three pieces, all optional and all off by default:
 
-``Tracer`` — a low-overhead span/event recorder.  The scheduler, the
-batch engines and the spec engine record *complete spans* (a name plus a
-``[t0, t1)`` wall-clock window on a named track), *instants* (admission,
-preemption, verdicts, terminal outcomes) and *counter samples* (pool
-occupancy, pressure, queue depth) into one bounded ring buffer
+``Tracer`` — a low-overhead span/event recorder.  The program brackets
+each layer boundary with ``Tracer.region(track, name)``, a context
+manager that records one complete span (a name plus a ``[t0, t1)``
+wall-clock window on a named track) into a bounded ring buffer
 (``collections.deque(maxlen=...)`` — a long run overwrites its oldest
-entries instead of growing without bound).  Tracks are strings:
+entries instead of growing without bound) with the id of the region it
+opened inside as ``parent``.  With ``annotate=True`` the same region is
+also a ``jax.profiler.TraceAnnotation``, so it lands on the profiler's
+host thread on the device trace's clock.  Region names are stable across
+model configurations (engines go by their role, ``base``/``draft``):
 
-    ``scheduler``      per-tick spans (batch composition, budget spent)
-    ``engine:<name>``  engine-call brackets (prefill/extend/decode/feed/
-                       cache_seed) per BatchEngine
-    ``req:<id>``       one track per request: queued -> prefill chunks ->
-                       speculate/verify/close/fallback/answer phase spans
-                       -> spec_round spans -> done
+    ``sched.tick``          one scheduler turn, containing
+      ``sched.admit``       deadline expiry, shedding, the ladder, admission
+      ``sched.prefill``     the bounded chunked-prefill batch
+      ``sched.speculate``   the drafting batch
+      ``sched.verify``      the scoring batch
+      ``sched.close``       the merged delimiter / ``</think>`` extend
+      ``sched.decode``      fallback regenerations and answers
+      ``sched.finish``      health scan, TTFT stamps, finishing, monitors
+    ``spec.round``          one batched spec-decode round, containing
+      ``spec.draft`` / ``spec.verify`` / ``spec.stage`` (acceptance
+      inputs staged to the device) / ``spec.accept`` / ``spec.reconcile``
+    ``spec.finish_feed``    the finished rows' pending-token feed
+    ``<role>.<op>``         one engine call (prefill/extend/decode/feed/
+                            cache_seed; ``spec.accept`` likewise), tiled by
+      ``.put``              host staging and host-to-device copies
+      ``.dispatch``         the jitted call (returns once enqueued)
+      ``.wait``             ``block_until_ready``
+      ``.pull``             device-to-host copies and host bookkeeping
+
+Tracks are strings: ``scheduler`` (``sched.*``), ``spec`` (``spec.*``),
+``engine:<name>`` (engine calls) and ``req:<id>``, one per request.
+Request tracks are the one place spans are recorded after the fact with
+``Tracer.span``: every phase of a batch shares the batch's interval, so
+they need no profiler copy.  Besides spans the ring holds *instants*
+(admission, preemption, verdicts, terminal outcomes) and *counter
+samples* (pool occupancy, pressure, queue depth).
 
 ``Tracer.chrome_trace()`` renders the buffer as Chrome trace-event JSON
 (``traceEvents`` with ``ph:"X"`` complete events, ``ph:"i"`` instants,
 ``ph:"C"`` counters and ``ph:"M"`` track-naming metadata — loadable in
 Perfetto / chrome://tracing).  Timestamps are microseconds relative to
-the tracer's epoch, so a ``jax.profiler`` capture taken in the same
-process lines up when the engines also wrap their dispatches in
-``jax.profiler.TraceAnnotation`` (``annotate=True``).
+the tracer's epoch.
 
 **Zero-cost-when-off contract:** tracing is off when the scheduler's
 ``tracer`` is ``None``; every call site guards with ``if tr is not
-None:`` BEFORE building span names or args dicts, so a tracer-less tick
-executes no telemetry code beyond the guard itself.  When on, recording
-is an epoch subtraction plus one deque append — no host syncs, no device
-dispatches, no PRNG use — so traced runs stay token-identical to
-untraced runs (tested in tests/test_telemetry.py; overhead gated <= 5%
-in benchmarks/bench_telemetry.py).
+None`` BEFORE building span names or args dicts (a region site takes
+the shared no-op ``NO_REGION`` instead), so a tracer-less tick executes
+no telemetry code beyond the guard itself.  When on, a region is two
+clock reads, a stack push/pop and one deque append (plus the profiler
+annotation under ``annotate``) — no host syncs, no device dispatches, no
+PRNG use — so traced runs stay token-identical to untraced runs (tested
+in tests/test_telemetry.py; overhead gated <= 5% in
+benchmarks/bench_telemetry.py).
 
 ``MetricsRegistry`` — Prometheus-style counters / gauges / histograms
 (fixed buckets for TTFT / TPOT / prefill-chunk latency / spec-decode
@@ -46,18 +69,22 @@ read ``.kind`` and ``.fields``.  An active tracer records every event as
 an instant on the owning track.
 
 Analyzer: ``tools/trace_report.py`` turns an exported trace into a
-per-request waterfall, a phase-attribution table and a speculation
-funnel (DESIGN.md §Observability)."""
+per-request waterfall, a phase-attribution table, the engine calls'
+put/dispatch/wait/pull split and a speculation funnel (DESIGN.md
+§Observability)."""
 
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import os
 import time
 from collections import deque
 from typing import (Any, Callable, Dict, Iterable, List, Mapping, Optional,
                     Sequence, Tuple)
+
+import jax
 
 
 def atomic_write(path: str, text: str) -> None:
@@ -109,7 +136,11 @@ class SchedEvent(str):
 
 # well-known track names (requests get "req:<id>")
 TRACK_SCHED = "scheduler"
+TRACK_SPEC = "spec"           # batched spec-decode rounds (spec_engine.py)
 TRACK_COMPILE = "compile"     # compile-sentinel events (compile_watch.py)
+
+# what a region site enters when tracing is off (reusable, reentrant)
+NO_REGION = contextlib.nullcontext()
 
 
 def engine_track(name: str) -> str:
@@ -127,10 +158,14 @@ class Tracer:
     store them relative to the tracer's construction epoch (clamped at
     zero, so a request submitted before the tracer existed still exports
     a valid non-negative span).  ``buffer`` bounds retained entries —
-    ``dropped`` counts what the ring overwrote.  ``annotate=True`` asks
-    the engines to additionally wrap their jitted dispatches in
-    ``jax.profiler.TraceAnnotation`` so device profiles line up with the
-    serving-phase spans."""
+    ``dropped`` counts what the ring overwrote.  ``annotate=True`` makes
+    every :meth:`region` also a ``jax.profiler.TraceAnnotation`` of the
+    same name, so a profiler capture in this process shows the program's
+    layers on the device trace's clock.
+
+    Regions nest by a stack of open region ids, so they are opened and
+    closed on one thread (the scheduler's); readers of the ring
+    (``entries``, ``chrome_trace``) may run on any thread."""
 
     def __init__(self, buffer: int = 65536, annotate: bool = False):
         if buffer < 1:
@@ -139,6 +174,8 @@ class Tracer:
         self.annotate = annotate
         self.recorded = 0            # total entries ever recorded
         self._buf: deque = deque(maxlen=int(buffer))
+        self._open: List[int] = []   # ids of the regions now open
+        self._ids = 0
 
     # ------------------------------------------------------------- record
     def now(self) -> float:
@@ -156,6 +193,16 @@ class Tracer:
         r0 = self._rel(t0)
         self._buf.append(("X", track, name, r0,
                           max(0.0, self._rel(t1) - r0), args))
+
+    def region(self, track: str, name: str, **args: Any) -> "Region":
+        """A context manager over one layer boundary: on exit it records
+        the span ``[enter, exit)`` with ``args`` plus its own ``id`` and
+        the ``parent`` id of the region it was opened inside (None at
+        the top).  Under ``annotate`` it is also a
+        ``jax.profiler.TraceAnnotation(name)``.  ``args`` may be filled
+        in inside the block (``with tr.region(...) as rg: rg.args[k] =
+        v``)."""
+        return Region(self, track, name, args)
 
     def instant(self, track: str, name: str,
                 args: Optional[Dict[str, Any]] = None,
@@ -258,6 +305,41 @@ class Tracer:
         truncated one — the crash-safe-flush contract serve.py's
         try/finally and --snapshot-every rely on."""
         atomic_write(path, json.dumps(self.chrome_trace()))
+
+
+class Region:
+    """One open :meth:`Tracer.region` (see there)."""
+
+    __slots__ = ("tracer", "track", "name", "args", "id", "parent", "t0",
+                 "_ann")
+
+    def __init__(self, tracer: Tracer, track: str, name: str,
+                 args: Dict[str, Any]):
+        self.tracer, self.track, self.name = tracer, track, name
+        self.args = args
+        self._ann = None
+
+    def __enter__(self) -> "Region":
+        tr = self.tracer
+        tr._ids += 1
+        self.id = tr._ids
+        self.parent = tr._open[-1] if tr._open else None
+        tr._open.append(self.id)
+        if tr.annotate:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        t1 = time.perf_counter()
+        tr = self.tracer
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr._open.pop()
+        self.args["id"] = self.id
+        self.args["parent"] = self.parent
+        tr.span(self.track, self.name, self.t0, t1, self.args)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Admin plane: in-process HTTP endpoint tests against a real drained
 scheduler (no subprocess — tools/admin_smoke.py covers the live-run
-path in CI).  Exercises all seven routes (including /roofline and the
-latched /profile), the 404 hints for absent substrates, ?last= ring
+path in CI).  Exercises all six routes (including the latched
+/profile), the 404 hints for absent substrates, ?last= ring
 slicing, the StatusBoard publish/latest handoff, and the crash-safe
 atomic artifact write."""
 
@@ -77,8 +77,7 @@ def served(tmp_path_factory):
     cs.drain(jax.random.PRNGKey(9))
     profiler = ProfilerCapture(str(tmp_path_factory.mktemp("xla_prof")))
     admin = AdminServer(board=board, metrics=metrics.registry,
-                        tracer=tracer, compile_watch=watch,
-                        profiler=profiler).start()
+                        tracer=tracer, profiler=profiler).start()
     yield {"admin": admin, "cs": cs, "tracer": tracer,
            "metrics": metrics, "handles": handles, "watch": watch,
            "profiler": profiler}
@@ -178,21 +177,6 @@ def test_trace_full_and_sliced(served):
     assert status == 400
 
 
-def test_roofline_endpoint_serves_live_join(served):
-    status, body = _get(served["admin"].port, "/roofline")
-    assert status == 200
-    doc = json.loads(body)
-    assert doc["compiles"] > 0 and doc["programs"] > 0
-    assert doc["warmup_ticks"] == served["watch"].warmup_ticks
-    assert doc["ops"], "drained run produced no per-op roofline rows"
-    ops = {(r["engine"], r["op"]) for r in doc["ops"]}
-    assert any(op == "prefill" for _, op in ops)
-    # tracing was on, so device time was measured and rates computed
-    assert any(r["gflops_per_s"] for r in doc["ops"])
-    # the endpoint serves exactly the watch's live aggregate
-    assert doc == json.loads(json.dumps(served["watch"].roofline()))
-
-
 def test_status_carries_compile_summary(served):
     status, body = _get(served["admin"].port, "/status")
     doc = json.loads(body)
@@ -227,15 +211,14 @@ def test_unknown_route_lists_routes(served):
     status, body = _get(served["admin"].port, "/nope")
     assert status == 404
     routes = json.loads(body)["routes"]
-    assert "/status" in routes and "/roofline" in routes
+    assert "/status" in routes
     assert "/profile?seconds=S" in routes
 
 
 def test_missing_substrates_404_with_hint():
     admin = AdminServer().start()            # nothing attached
     try:
-        for path in ("/metrics", "/trace", "/requests/x", "/roofline",
-                     "/profile"):
+        for path in ("/metrics", "/trace", "/requests/x", "/profile"):
             status, body = _get(admin.port, path)
             assert status == 404, path
             assert "error" in json.loads(body), path

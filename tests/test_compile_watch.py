@@ -109,17 +109,6 @@ def test_sentinel_counts_distinct_signatures_once():
     assert cw.as_dict()["programs"] == 2
     assert cw.as_dict()["compiles"] == 2
     assert len(cw.signatures("e", "op")) == 2
-    rl = cw.roofline()
-    row = rl["ops"][0]
-    assert (row["engine"], row["op"]) == ("e", "op")
-    assert row["calls"] == 4 and row["compiles"] == 2
-    assert row["flops"] > 0 and row["bytes"] > 0
-    # no device time fed back -> rates stay None, never divide-by-zero
-    assert row["gflops_per_s"] is None and row["gbytes_per_s"] is None
-    cw.note_device("e", "op", 0.5)
-    row = cw.roofline()["ops"][0]
-    assert row["gflops_per_s"] == pytest.approx(row["flops"] / 0.5 / 1e9)
-    assert row["intensity"] == pytest.approx(row["flops"] / row["bytes"])
 
 
 def test_sentinel_warmup_window_and_monitor_feed():
@@ -192,29 +181,8 @@ def test_steady_state_drain_has_zero_post_warmup_recompiles(engine_pair):
         f"recompile storm in steady state: {after}"
     assert after["compiles"] == warm["compiles"]
     # the spec-decode acceptance program is among the watched ops
-    ops = {op for (_, op) in cw._agg}
-    assert "accept_prog" in ops and "prefill" in ops
-
-
-def test_full_plane_run_populates_roofline_join(engine_pair):
-    reqs, keys = _workload(seed=12)
-    ctrl = _mk_controller(engine_pair, spec=True)
-    tr, mt = Tracer(), ServingMetrics()
-    cw = CompileWatch(tracer=tr, metrics=mt)
-    cs = _mk_sched(ctrl, tracer=tr, metrics=mt, compile_watch=cw)
-    _drain(cs, reqs, keys)
-    rl = cw.roofline()
-    assert rl["ops"]
-    synced = [r for r in rl["ops"] if r["device_s"] > 0]
-    assert synced, "tracing on but no device time fed back"
-    for r in synced:
-        if r["flops"] > 0:
-            assert r["gflops_per_s"] > 0
-    # the parent engine spans carry the cost annotations for the
-    # offline (trace_report) twin of the same join
-    flopped = [args for (_, trk, name, _, _, args) in tr.entries()
-               if trk.startswith("engine:") and "flops" in args]
-    assert flopped and any(a["flops"] for a in flopped)
+    assert cw.signatures(cs.base_be.name, "accept_prog")
+    assert cw.signatures(cs.base_be.name, "prefill")
 
 
 # ------------------------------------------------------- token identity

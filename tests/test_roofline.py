@@ -2,12 +2,8 @@
 cost_analysis() on unrolled programs and correctly multiply while-loop
 bodies by trip counts (which cost_analysis does NOT); the compile
 sentinel's live cost capture must join against the same parser on the
-engines' paged prefill/extend/feed jits; and the trace analyzer's
-roofline view must exclude the host/device sub-spans (no double
-counting)."""
+engines' paged prefill/extend/feed jits."""
 
-import importlib.util
-import os
 import random
 
 import jax
@@ -18,16 +14,6 @@ from repro.roofline.hlo_cost import HloModule, module_cost
 from repro.roofline.analysis import model_flops_estimate
 from repro.models.config import INPUT_SHAPES
 from repro.configs.registry import ARCHS
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_trace_report():
-    spec = importlib.util.spec_from_file_location(
-        "trace_report", os.path.join(ROOT, "tools", "trace_report.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _ca(compiled):
@@ -187,45 +173,6 @@ def test_sentinel_cost_joins_hlo_cost_on_engine_jits():
                 f"cost_analysis {cost['flops']}"
             checked += 1
     assert checked > 0, "no prefill/extend/feed programs captured"
-
-
-def test_trace_report_roofline_view_excludes_subspans():
-    """The analyzer's roofline view counts the parent bracket span once
-    — never its .dispatch / .block_until_ready tiles — and reads device
-    time ONLY off .block_until_ready.  Compile-track spans feed the
-    compile columns."""
-    rep = _load_trace_report()
-    tracks = {1: "engine:e", 2: "compile"}
-    events = [
-        {"ph": "X", "tid": 1, "name": "decode", "ts": 0.0, "dur": 100.0,
-         "args": {"flops": 1000.0, "hlo_bytes": 400.0, "tokens": 4}},
-        {"ph": "X", "tid": 1, "name": "decode.dispatch", "ts": 0.0,
-         "dur": 40.0, "args": {"side": "host"}},
-        {"ph": "X", "tid": 1, "name": "decode.block_until_ready",
-         "ts": 40.0, "dur": 60.0, "args": {"side": "device"}},
-        {"ph": "X", "tid": 2, "name": "e.decode", "ts": 0.0, "dur": 5.0,
-         "args": {"post_warmup": False}},
-        {"ph": "X", "tid": 2, "name": "e.decode", "ts": 50.0, "dur": 5.0,
-         "args": {"post_warmup": True}},
-    ]
-    data = rep.roofline_data(events, tracks)
-    assert len(data["ops"]) == 1
-    row = data["ops"][0]
-    assert (row["engine"], row["op"]) == ("e", "decode")
-    assert row["calls"] == 1                 # parent only, not 3
-    assert row["flops"] == 1000.0            # stamped once, not tripled
-    assert row["bytes"] == 400.0
-    assert row["device_ms"] == pytest.approx(0.06)
-    assert row["compiles"] == 2 and row["post_warmup_compiles"] == 1
-    # rates are rounded to 3 decimals by the renderer
-    assert row["gflops_per_s"] == round(1000.0 / 60e-6 / 1e9, 3)
-    assert row["gbytes_per_s"] == round(400.0 / 60e-6 / 1e9, 3)
-    assert row["intensity"] == pytest.approx(2.5)
-    assert data["compiles"] == 2 and data["post_warmup_compiles"] == 1
-    # text renderer survives both populated and empty inputs
-    assert "e" in rep.roofline_text(data)
-    assert "predates" in rep.roofline_text({"ops": [], "compiles": 0,
-                                            "post_warmup_compiles": 0})
 
 
 def test_model_flops_estimate_scaling():

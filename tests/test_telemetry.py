@@ -1,17 +1,22 @@
 """Structured tracing & metrics: Chrome-trace schema round-trip (spans
 nest inside request lifetimes, analyzer validation passes), ring-buffer
-bounding, structured-event back-compat rendering, Prometheus exposition,
-token identity of traced vs untraced runs (greedy / sampled /
-spec-decode / prefix-cache), and the sequential-path ok-status stamping
-regression."""
+bounding, the region API on the profiler's clock (nesting and parent
+links in the ring and in the xplane host line, nothing recorded with
+tracing off), the engines' device scopes, the committed-token counter,
+structured-event back-compat rendering, Prometheus exposition, token
+identity of traced vs untraced runs (greedy / sampled / spec-decode /
+prefix-cache), and the sequential-path ok-status stamping regression."""
 
+import glob
 import importlib.util
 import json
 import os
 import random
-from collections import defaultdict
+import re
+from collections import Counter, defaultdict
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.core.controller import SpecReason, SpecReasonConfig
@@ -217,6 +222,173 @@ def test_trace_round_trip_spans_nest_and_cover_lifetime(engine_pair,
                 f"{track}: {e['name']} outside request lifetime"
     # the full analyzer also renders from it without failing
     assert rep.main([str(path)]) == 0
+
+
+# ----------------------------------------------------- regions
+
+
+REGION = re.compile(r"(sched|spec|base|draft)(\.[a-z_]+)+$")
+
+
+def _xplane_regions(trace_dir):
+    """[name, start_ns, end_ns] of every program region on the
+    profiler's host threads, by start (a parent before its child)."""
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out += [[ev.name, ev.start_ns, ev.start_ns + ev.duration_ns]
+                    for ev in line.events if REGION.match(ev.name)]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+@pytest.fixture(scope="module")
+def profiled(engine_pair, tmp_path_factory):
+    """One spec-decode workload drained twice under the profiler: with an
+    annotating tracer, then with tracing off."""
+    reqs, keys = _workload(n_requests=2, seed=10, min_steps=4, max_steps=5)
+    ctrl = _mk_controller(engine_pair, spec=True)
+    # the program's regions are host annotations: no Python tracer needed
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    out = {}
+    for arm, tr in (("on", Tracer(annotate=True)), ("off", None)):
+        d = str(tmp_path_factory.mktemp(f"xplane_{arm}"))
+        cs = _mk_sched(ctrl, tracer=tr)
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            handles = _drain(cs, reqs, keys)
+        finally:
+            jax.profiler.stop_trace()
+        out[arm] = (tr, handles, _xplane_regions(d))
+    return out
+
+
+def _ancestors(evs, i):
+    """Names of the events that contain ``evs[i]``, innermost first
+    (``evs`` as ``_xplane_regions`` orders them)."""
+    _, s, e = evs[i]
+    return [n for n, s2, e2 in reversed(evs[:i]) if s2 <= s and e <= e2]
+
+
+def test_regions_nest_on_the_profiler_clock(profiled):
+    tr, _, evs = profiled["on"]
+    ring = sorted((x for x in tr.entries() if x[0] == "X" and x[5]
+                   and "parent" in x[5]), key=lambda x: (x[3], -x[4]))
+    # every region reached the profiler's host line, once per ring span
+    assert Counter(e[0] for e in evs) == Counter(x[2] for x in ring)
+    by_id = {x[5]["id"]: x for x in ring}
+    for i, x in enumerate(ring):
+        # the ring's parent link names the innermost region over it on
+        # the profiler's clock
+        inner = _ancestors(evs, i)
+        if x[5]["parent"] is None:
+            assert x[2] == "sched.tick" and not inner
+        else:
+            parent = by_id[x[5]["parent"]]
+            assert inner[0] == parent[2] and evs[i][0] == x[2]
+            assert parent[3] <= x[3] <= x[3] + x[4] <= parent[3] + parent[4]
+    # engines go by their role: the drafter drafts inside spec rounds
+    draft = [_ancestors(evs, i) for i, e in enumerate(evs)
+             if e[0] == "draft.decode"]
+    assert any("spec.draft" in c for c in draft)
+    chains = [_ancestors(evs, i) for i, e in enumerate(evs)
+              if e[0] == "base.extend.pull"]
+    want = ["base.extend", "spec.round", "sched.tick"]
+    assert any([n for n in c if n in want] == want
+               and {"sched.speculate", "sched.decode"} & set(c)
+               for c in chains)
+
+
+def test_untraced_run_records_no_region(profiled):
+    _, on, _ = profiled["on"]
+    tr, off, evs = profiled["off"]
+    assert tr is None and evs == []
+    _assert_identical(on, off)
+
+
+def test_engine_programs_carry_device_scopes(engine_pair):
+    """The base extend's lowered HLO names every layer of the device
+    work (the scopes leave the arithmetic alone)."""
+    be = _mk_sched(_mk_controller(engine_pair)).base_be
+    toks = jnp.zeros((be.batch, 8), jnp.int32)
+    text = be._prefill_fn(64).lower(be.params, toks, be.state).as_text(
+        debug_info=True)
+    paths = {part for loc in re.findall(r'loc\("([^"]*)"', text)
+             for part in loc.split("/")}
+    assert {"attn", "kv_write", "kv_copy", "mlp", "lm_head"} <= paths
+
+
+def test_snapshot_rows_count_committed_tokens(engine_pair):
+    reqs, keys = _workload(seed=11)
+    cs = _mk_sched(_mk_controller(engine_pair, spec=True))
+    handles = [cs.submit(t, key=k) for t, k in zip(reqs, keys)]
+    key, rows = jax.random.PRNGKey(9), 0
+    while True:
+        key, sub = jax.random.split(key)
+        working = cs.tick(sub)
+        snap = cs.snapshot()
+        live = {a.req.request_id: a.state for a in cs.active}
+        for row in snap.active:
+            st = live[row["request"]]
+            assert row["tokens"] == len(st.thinking) + len(st.answer_ids)
+            rows += 1
+        if not working:
+            break
+    assert rows
+    assert snap.counts["committed_tokens"] == sum(
+        len(h.result.thinking_ids) + len(h.result.answer_ids)
+        for h in handles)
+
+
+def test_region_records_parent_and_args():
+    tr = Tracer()
+    with tr.region("scheduler", "sched.tick", tick=1) as outer:
+        with tr.region("engine:e", "base.feed") as inner:
+            inner.args["rows"] = 2
+    feed, tick = tr.entries()                  # recorded as they close
+    assert tick[2] == "sched.tick" and tick[5] == {
+        "tick": 1, "id": outer.id, "parent": None}
+    assert feed[1:3] == ("engine:e", "base.feed")
+    assert feed[5] == {"rows": 2, "id": inner.id, "parent": outer.id}
+    assert tick[3] <= feed[3] and feed[3] + feed[4] <= tick[3] + tick[4]
+
+
+def test_trace_report_hostdev_reads_engine_phases():
+    """The engine-call view splits each call into its four phases and
+    counts the call once, wherever the phases sit."""
+    rep = _load_trace_report()
+    tracks = {1: "engine:cb-tb", 2: "spec"}
+    events = [
+        {"ph": "X", "tid": 1, "name": "base.extend", "ts": 0.0,
+         "dur": 100.0, "args": {"tokens": 4, "kv_bytes": 1 << 20}},
+        {"ph": "X", "tid": 1, "name": "base.extend.put", "ts": 0.0,
+         "dur": 10.0},
+        {"ph": "X", "tid": 1, "name": "base.extend.dispatch", "ts": 10.0,
+         "dur": 20.0},
+        {"ph": "X", "tid": 1, "name": "base.extend.wait", "ts": 30.0,
+         "dur": 60.0},
+        {"ph": "X", "tid": 1, "name": "base.extend.pull", "ts": 90.0,
+         "dur": 10.0},
+        {"ph": "X", "tid": 2, "name": "spec.accept", "ts": 100.0,
+         "dur": 50.0},
+        {"ph": "X", "tid": 2, "name": "spec.accept.wait", "ts": 110.0,
+         "dur": 40.0},
+        {"ph": "X", "tid": 2, "name": "spec.round", "ts": 0.0,
+         "dur": 150.0},
+    ]
+    rows = {r["op"]: r for r in rep.hostdev_data(events, tracks)["ops"]}
+    assert set(rows) == {"base.extend", "spec.accept"}
+    ext = rows["base.extend"]
+    assert ext["calls"] == 1 and ext["tokens"] == 4 and ext["kv_mb"] == 1.0
+    assert (ext["put_ms"], ext["dispatch_ms"], ext["wait_ms"],
+            ext["pull_ms"]) == (0.01, 0.02, 0.06, 0.01)
+    assert rows["spec.accept"]["wait_ms"] == 0.04
+    # the attribution view leaves the phases out, the calls in
+    attr = rep.attribution_data(events, tracks)["tracks"]
+    assert [r["phase"] for r in attr["engine:cb-tb"]] == ["base.extend"]
+    assert "(no engine-call" in rep.hostdev_text({"ops": []})
 
 
 # ----------------------------------------------------- token identity

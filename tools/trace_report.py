@@ -4,43 +4,34 @@ from the serving driver into human-readable tables or one JSON doc.
   python tools/trace_report.py out.json
   python tools/trace_report.py out.json --json > report.json
 
-Five views, all from the one artifact:
+Four views, all from the one artifact:
 
 * **Waterfall** — per request, the phase timeline in submission order:
   queued / prefill chunks / speculate / verify / fallback / close /
   answer spans with start offset and duration, so "where did this
   request's wall time go" reads top to bottom.
-* **Phase attribution** — per track (scheduler, each engine, requests
-  pooled), total span time per phase name and its share of the trace's
-  wall window.  Engine rows attribute device-dispatch brackets
-  (prefill / decode / extend / feed / cache_seed / accept_prog);
-  request rows attribute scheduler phases.  The ``.dispatch`` /
-  ``.block_until_ready`` sub-spans are EXCLUDED here — they tile their
-  parent bracket, so summing them alongside it would double-count.
-* **Host/device attribution** — per engine call op, calls and total
-  time split into host ms (the ``.dispatch`` sub-spans: argument
-  staging + the jitted call, which returns once the device work is
-  enqueued) and device ms (the ``.block_until_ready`` sub-spans: the
-  wait for device completion), plus the static cost annotations summed
-  off the parent spans (tokens, est. KV MB moved).
-* **Roofline** — per engine call op, the compile sentinel's
-  cost-model FLOPs / bytes accessed (the ``flops`` / ``hlo_bytes``
-  annotations the sentinel stamps on every parent bracket span) joined
-  against measured device seconds (the ``.block_until_ready``
-  sub-spans): achieved GFLOP/s, GB/s and arithmetic intensity, plus
-  compile counts off the ``compile`` track (post-warmup compiles are
-  recompile-storm evidence).  Parent spans only — sub-spans tile their
-  parent, so the same exclusion rule as the attribution view applies.
-  Absent rates mean no device time was measured for that op (tracing
-  predates the compile sentinel, or the op never host-syncs, e.g.
-  ``cache_seed``).
+* **Phase attribution** — per track (scheduler, spec rounds, each
+  engine, requests pooled), total span time per region or phase name
+  and its share of the trace's wall window.  Engine rows attribute
+  engine calls (``<role>.prefill`` / ``.extend`` / ``.decode`` /
+  ``.feed`` / ``.cache_seed``); request rows attribute scheduler
+  phases.  The ``.put`` / ``.dispatch`` / ``.wait`` / ``.pull`` phases
+  are EXCLUDED here — they tile their engine call, so summing them
+  alongside it would double-count.
+* **Engine-call phases** (``hostdev``) — per engine call
+  (``<role>.<op>``, and the spec engine's ``spec.accept``), calls and
+  milliseconds in each phase: ``put`` (host staging and copies to the
+  device), ``dispatch`` (the jitted call, which returns once the work
+  is enqueued), ``wait`` (``block_until_ready``) and ``pull`` (copies
+  back and host bookkeeping), plus the static cost annotations summed
+  off the call spans (tokens, est. KV MB moved).
 * **Speculation funnel** — proposed vs accepted draft tokens summed
   over every spec_round span, step-level accept/reject instants, and
   fallback regenerations: the proposed → accepted → fallback shape of
   the run.
 
-``--json`` emits all five as one machine-readable document
-(``{meta, waterfall, attribution, hostdev, roofline, funnel}``) so CI
+``--json`` emits all four as one machine-readable document
+(``{meta, waterfall, attribution, hostdev, funnel}``) so CI
 and scripts gate on trace contents instead of scraping stdout.
 
 The loader *validates* before it renders — required keys per event
@@ -63,9 +54,9 @@ from collections import defaultdict
 REQUEST_PHASES = ("queued", "prefill", "speculate", "verify", "fallback",
                   "close", "answer", "spec_round")
 
-# host/device sub-span suffixes (batch_engine._bracket / the spec
-# engine's accept_prog bracket)
-_SUB_SUFFIXES = (".dispatch", ".block_until_ready")
+# the phases that tile one engine call (serving/telemetry.py's regions)
+PHASES = ("put", "dispatch", "wait", "pull")
+_SUB_SUFFIXES = tuple("." + p for p in PHASES)
 
 
 def _is_subspan(name: str) -> bool:
@@ -230,154 +221,50 @@ def attribution_text(data: dict) -> str:
     return "\n".join(lines)
 
 
-# -------------------------------------------------- host/device view
+# -------------------------------------------------- engine-call phases
 def hostdev_data(events: list, tracks: dict) -> dict:
-    """Host-vs-device time per engine call op, from the bracket
-    sub-spans: host = ``.dispatch`` (staging + enqueue), device =
-    ``.block_until_ready`` (the completion wait).  Calls / tokens /
-    KV bytes are summed off the parent spans' static annotations."""
-    per = defaultdict(lambda: {"calls": 0, "host_us": 0.0,
-                               "device_us": 0.0, "tokens": 0,
-                               "kv_bytes": 0})
+    """Milliseconds in each phase (put / dispatch / wait / pull) per
+    engine call, from the phase spans; calls / tokens / KV bytes are
+    summed off the call spans' static annotations."""
+    per = defaultdict(lambda: {"calls": 0, "tokens": 0, "kv_bytes": 0,
+                               **{p: 0.0 for p in PHASES}})
+    calls = []
     for e in events:
         if e.get("ph") != "X":
             continue
-        track = tracks.get(e["tid"], "?")
-        if not track.startswith("engine:"):
-            continue
-        engine = track[len("engine:"):]
-        name = e["name"]
-        if name.endswith(".dispatch"):
-            per[(engine, name[:-len(".dispatch")])]["host_us"] += e["dur"]
-        elif name.endswith(".block_until_ready"):
-            per[(engine, name[:-len(".block_until_ready")])][
-                "device_us"] += e["dur"]
+        op, _, phase = e["name"].rpartition(".")
+        if phase in PHASES:
+            per[op][phase] += e["dur"]
         else:
-            d = per[(engine, name)]
+            calls.append(e)
+    for e in calls:
+        d = per.get(e["name"])
+        if d is not None:
             d["calls"] += 1
             args = e.get("args") or {}
             d["tokens"] += args.get("tokens", 0)
             d["kv_bytes"] += args.get("kv_bytes", 0)
-    engines = defaultdict(list)
-    for (engine, op), d in sorted(
-            per.items(), key=lambda kv: -(kv[1]["host_us"]
-                                          + kv[1]["device_us"])):
-        total = d["host_us"] + d["device_us"]
-        engines[engine].append({
-            "op": op,
-            "calls": d["calls"],
-            "host_ms": round(d["host_us"] / 1e3, 3),
-            "device_ms": round(d["device_us"] / 1e3, 3),
-            "device_share": round(d["device_us"] / total, 4)
-            if total else 0.0,
-            "tokens": d["tokens"],
-            "kv_mb": round(d["kv_bytes"] / (1 << 20), 3),
-        })
-    return {"engines": dict(engines)}
+    ops = []
+    for op, d in sorted(per.items(),
+                        key=lambda kv: -sum(kv[1][p] for p in PHASES)):
+        ops.append({"op": op, "calls": d["calls"],
+                    **{f"{p}_ms": round(d[p] / 1e3, 3) for p in PHASES},
+                    "tokens": d["tokens"],
+                    "kv_mb": round(d["kv_bytes"] / (1 << 20), 3)})
+    return {"ops": ops}
 
 
 def hostdev_text(data: dict) -> str:
-    lines = ["== host/device attribution =="]
-    if not data["engines"]:
-        return "\n".join(lines + ["(no engine bracket sub-spans — trace "
-                                  "predates host/device attribution)"])
-    lines.append(f"{'engine':<22} {'op':<12} {'calls':>6} {'host':>9} "
-                 f"{'device':>9} {'dev%':>6} {'tokens':>8} {'kv MB':>8}")
-    for engine, rows in data["engines"].items():
-        for r in rows:
-            lines.append(
-                f"{engine:<22} {r['op']:<12} {r['calls']:>6} "
-                f"{r['host_ms']:>7.1f}ms {r['device_ms']:>7.1f}ms "
-                f"{r['device_share']:>6.1%} {r['tokens']:>8} "
-                f"{r['kv_mb']:>8.2f}")
-    return "\n".join(lines)
-
-
-# ------------------------------------------------------------- roofline
-def roofline_data(events: list, tracks: dict) -> dict:
-    """Achieved-rate roofline per engine call op: the compile sentinel's
-    cost-model FLOPs / bytes (``flops`` / ``hlo_bytes`` parent-span
-    annotations) over measured device seconds (``.block_until_ready``
-    sub-spans).  Sub-spans are EXCLUDED from the call/flop sums — they
-    tile their parent bracket (same rule as the attribution view), so
-    only ``.block_until_ready`` durations feed the denominator.
-    Compile counts come off the ``compile`` track."""
-    per = defaultdict(lambda: {"calls": 0, "flops": 0.0, "bytes": 0.0,
-                               "device_us": 0.0, "compiles": 0,
-                               "post_warmup_compiles": 0})
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        track = tracks.get(e["tid"], "?")
-        name = e["name"]
-        if track == "compile":
-            # span name is "<engine>.<op>"; op names never contain dots
-            engine, _, op = name.rpartition(".")
-            d = per[(engine, op)]
-            d["compiles"] += 1
-            if (e.get("args") or {}).get("post_warmup"):
-                d["post_warmup_compiles"] += 1
-            continue
-        if not track.startswith("engine:"):
-            continue
-        engine = track[len("engine:"):]
-        if name.endswith(".block_until_ready"):
-            per[(engine, name[:-len(".block_until_ready")])][
-                "device_us"] += e["dur"]
-        elif not _is_subspan(name):
-            d = per[(engine, name)]
-            d["calls"] += 1
-            args = e.get("args") or {}
-            d["flops"] += args.get("flops") or 0.0
-            d["bytes"] += args.get("hlo_bytes") or 0.0
-    ops = []
-    for (engine, op), d in sorted(per.items(),
-                                  key=lambda kv: -kv[1]["flops"]):
-        dev_s = d["device_us"] / 1e6
-        row = {
-            "engine": engine, "op": op, "calls": d["calls"],
-            "compiles": d["compiles"],
-            "post_warmup_compiles": d["post_warmup_compiles"],
-            "flops": d["flops"], "bytes": d["bytes"],
-            "device_ms": round(d["device_us"] / 1e3, 3),
-            "gflops_per_s": round(d["flops"] / dev_s / 1e9, 3)
-            if dev_s > 0 and d["flops"] > 0 else None,
-            "gbytes_per_s": round(d["bytes"] / dev_s / 1e9, 3)
-            if dev_s > 0 and d["bytes"] > 0 else None,
-            "intensity": round(d["flops"] / d["bytes"], 3)
-            if d["bytes"] > 0 else None,
-        }
-        ops.append(row)
-    return {
-        "ops": ops,
-        "compiles": sum(r["compiles"] for r in ops),
-        "post_warmup_compiles": sum(r["post_warmup_compiles"]
-                                    for r in ops),
-    }
-
-
-def roofline_text(data: dict) -> str:
-    lines = ["== roofline (cost model x measured device time) =="]
+    lines = ["== engine-call phases =="]
     if not data["ops"]:
-        return "\n".join(lines + ["(no engine spans — trace predates "
-                                  "the compile sentinel)"])
-    lines.append(f"{'engine':<22} {'op':<12} {'calls':>6} {'compiles':>8} "
-                 f"{'GFLOP':>9} {'GB':>8} {'dev ms':>9} {'GFLOP/s':>9} "
-                 f"{'GB/s':>8} {'F/B':>7}")
+        return "\n".join(lines + ["(no engine-call phase spans)"])
+    lines.append(f"{'op':<18} {'calls':>6} {'put':>9} {'dispatch':>9} "
+                 f"{'wait':>9} {'pull':>9} {'tokens':>8} {'kv MB':>8}")
     for r in data["ops"]:
-        comp = str(r["compiles"])
-        if r["post_warmup_compiles"]:
-            comp += f"(+{r['post_warmup_compiles']})"
-        gf = f"{r['gflops_per_s']:.2f}" if r["gflops_per_s"] else "-"
-        gb = f"{r['gbytes_per_s']:.2f}" if r["gbytes_per_s"] else "-"
-        ai = f"{r['intensity']:.2f}" if r["intensity"] else "-"
         lines.append(
-            f"{r['engine']:<22} {r['op']:<12} {r['calls']:>6} {comp:>8} "
-            f"{r['flops'] / 1e9:>9.3f} {r['bytes'] / 1e9:>8.3f} "
-            f"{r['device_ms']:>7.1f}ms {gf:>9} {gb:>8} {ai:>7}")
-    lines.append(f"compiles: {data['compiles']} total, "
-                 f"{data['post_warmup_compiles']} post-warmup "
-                 f"(nonzero post-warmup = recompile churn)")
+            f"{r['op']:<18} {r['calls']:>6} {r['put_ms']:>7.1f}ms "
+            f"{r['dispatch_ms']:>7.1f}ms {r['wait_ms']:>7.1f}ms "
+            f"{r['pull_ms']:>7.1f}ms {r['tokens']:>8} {r['kv_mb']:>8.2f}")
     return "\n".join(lines)
 
 
@@ -436,7 +323,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true",
                     help="emit all views as one machine-readable JSON "
                          "doc ({meta, waterfall, attribution, hostdev, "
-                         "roofline, funnel}) instead of text tables")
+                         "funnel}) instead of text tables")
     args = ap.parse_args(argv)
     try:
         doc = load(args.trace)
@@ -461,7 +348,6 @@ def main(argv=None) -> int:
             "waterfall": waterfall_data(events, tracks),
             "attribution": attribution_data(events, tracks),
             "hostdev": hostdev_data(events, tracks),
-            "roofline": roofline_data(events, tracks),
             "funnel": funnel_data(events, tracks),
         }, indent=1))
         return 0
@@ -477,8 +363,6 @@ def main(argv=None) -> int:
     print(attribution_text(attribution_data(events, tracks)))
     print()
     print(hostdev_text(hostdev_data(events, tracks)))
-    print()
-    print(roofline_text(roofline_data(events, tracks)))
     print()
     print(funnel_text(funnel_data(events, tracks)))
     return 0
