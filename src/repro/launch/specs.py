@@ -44,8 +44,8 @@ def abstract_decode_state(cfg: ModelConfig, batch: int, capacity: int,
     k = v = conv = ssm = ck = cv = None
     if cfg.has_attention:
         n_attn = cfg.n_self_layers if cfg.family == "vlm" else cfg.n_layers
-        k = _sds((n_attn, batch, capacity, kv, hd), dtype)
-        v = _sds((n_attn, batch, capacity, kv, hd), dtype)
+        k = _sds((n_attn, batch, capacity, kv * hd), dtype)
+        v = _sds((n_attn, batch, capacity, kv * hd), dtype)
     if cfg.has_ssm:
         ch = cfg.ssm_d_inner + 2 * cfg.ssm_n_groups * cfg.ssm_state
         conv = _sds((cfg.n_layers, batch, cfg.ssm_conv_width - 1, ch), dtype)
@@ -102,23 +102,33 @@ def _state_pspec(cfg: ModelConfig, state: DecodeState, batch_axes,
     """PartitionSpec tree matching a DecodeState (shape/divisibility
     aware).  For the self-attention cache: prefer kv heads on "model";
     when they don't divide, DECODE shards the sequence dim instead
-    (sequence-parallel flash-decode — §Perf iteration q2: the hd-sharded
-    fallback costs an f32 cache all-gather per layer per token), while
-    PREFILL falls back to head_dim (mirroring the weight sharding)."""
+    (sequence-parallel flash-decode — §Perf iteration q2: an hd-sharded
+    cache costs an f32 cache all-gather per layer per token), while
+    PREFILL keeps it unsplit."""
     b = batch_axes
     msize = mesh.shape["model"]
 
-    def kv_spec(x, is_self_cache=False):
+    def kv_spec(x):
         if x is None:
             return None
-        # (L, B, C, K, hd)
+        # cross caches (L, B, C, K, hd)
         if x.shape[3] % msize == 0:
             return P(None, b, shard_seq, "model", None)
-        if decode and is_self_cache and x.shape[2] % msize == 0:
-            return P(None, b, "model", None, None)
         if x.shape[4] % msize == 0:
             return P(None, b, shard_seq, None, "model")
         return P(None, b, shard_seq, None, None)
+
+    def self_kv_spec(x):
+        if x is None:
+            return None
+        # (L, B, C, K*hd): whole kv heads on "model" when they divide; a
+        # head_dim split is no slice of the heads-major minor dim, so
+        # prefill then keeps the cache unsplit
+        if cfg.n_kv_heads % msize == 0:
+            return P(None, b, shard_seq, "model")
+        if decode and x.shape[2] % msize == 0:
+            return P(None, b, "model", None)
+        return P(None, b, shard_seq, None)
 
     def ssm_spec(x):
         if x is None:
@@ -137,7 +147,7 @@ def _state_pspec(cfg: ModelConfig, state: DecodeState, batch_axes,
                 else P(None, b, None, None))
 
     return DecodeState(
-        k=kv_spec(state.k, True), v=kv_spec(state.v, True),
+        k=self_kv_spec(state.k), v=self_kv_spec(state.v),
         conv=conv_spec(state.conv), ssm=ssm_spec(state.ssm),
         cross_k=kv_spec(state.cross_k), cross_v=kv_spec(state.cross_v),
         pos=P(), ring=state.ring)

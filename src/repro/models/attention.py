@@ -14,6 +14,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .config import ModelConfig
 from .layers import ParamSpec, apply_rope
@@ -232,27 +233,55 @@ def cross_kv(kv_src: jax.Array, p: Dict[str, jax.Array]) -> Tuple[jax.Array, jax
 
 
 # ---------------------------------------------------------------------------
-# Decode path (one token, KV cache)
+# Cached paths: one token (decode) or a chunk (prefill) against the KV cache
 # ---------------------------------------------------------------------------
+#
+# Both take the WHOLE stacked cache (L, B, C, K*hd) (kvcache.DecodeState)
+# and the layer to work on.  The model's layer scan carries that cache, so
+# each layer writes only its new tokens' slots in place and reads its first
+# ``width`` slots (the attended window) with one ``dynamic_slice``: no
+# per-layer restack of the cache, and no copy of it when the caller
+# donates it.
+
+
+def _stored_layout(cache: jax.Array) -> jax.Array:
+    """Pin the carried cache to the row-major layout it is stored in.
+    Left free, the compiler lays the scan's carry out as the decode
+    read's dot likes it (slot-minor) and copies the whole cache into and
+    out of that layout on every call."""
+    return with_layout_constraint(
+        cache, Layout(major_to_minor=tuple(range(cache.ndim))))
+
+
+def _window(cache: jax.Array, layer, width: int, kv_heads: int
+            ) -> jax.Array:
+    """Slots ``[0, width)`` of layer ``layer``: (B, width, K, hd)."""
+    _, b, _, f = cache.shape
+    w = jax.lax.dynamic_slice(cache, (layer, 0, 0, 0), (1, b, width, f))[0]
+    return w.reshape(b, width, kv_heads, f // kv_heads)
+
 
 def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
                           cfg: ModelConfig, k_cache: jax.Array,
-                          v_cache: jax.Array, pos: jax.Array,
-                          ring: bool = False,
+                          v_cache: jax.Array, layer, pos: jax.Array,
+                          width: int, ring: bool = False,
                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode.
 
-    x: (B, 1, d); k_cache/v_cache: (B, C, K, hd) where C = max_len (linear)
-    or window (ring buffer).  pos: int32 — number of tokens already in
-    context (the new token's absolute position).  Either a scalar (all
-    rows aligned — the single-request engine) or a (B,) vector (ragged
-    rows — the continuous-batching engine): with a vector, each row writes
-    at its own slot and masks by its own length.
+    x: (B, 1, d); k_cache/v_cache: the stacked (L, B, C, K*hd) caches,
+    C = max_len (linear) or window (ring buffer); ``layer`` picks this
+    layer's.  pos: int32 — number of tokens already in context (the new
+    token's absolute position).  Either a scalar (all rows aligned — the
+    single-request engine) or a (B,) vector (ragged rows — the
+    continuous-batching engine): with a vector, each row writes at its own
+    slot and masks by its own length.  Attends slots ``[0, width)``
+    (static; ``width == C`` for a ring buffer): the caller keeps every
+    written position below it.
 
     Returns (attn_out (B,1,d), new_k_cache, new_v_cache).
     """
     b, _, _ = x.shape
-    cap = k_cache.shape[1]
+    cap = k_cache.shape[2]
     per_row = jnp.ndim(pos) == 1
     with jax.named_scope("attn"):
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -266,20 +295,11 @@ def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
             q = apply_rope(q, posv, cfg.rope_theta)
             k = apply_rope(k, posv, cfg.rope_theta)
 
-    slot = (pos % cap) if ring else jnp.minimum(pos, cap - 1)
+    slot = ((pos % cap) if ring else jnp.minimum(pos, cap - 1)
+            ).astype(jnp.int32)
     with jax.named_scope("kv_write"):
-        if per_row:
-            # Vectorized one-hot select instead of a batched scatter: XLA
-            # CPU lowers the scatter to a scalar loop over the whole
-            # (B, C, K, hd) cache (measured ~6x per-token cost at B=8);
-            # the select is a plain vector op over the same buffer.
-            hot = (jnp.arange(cap)[None, :]
-                   == slot[:, None])[:, :, None, None]
-            k_cache = jnp.where(hot, k.astype(k_cache.dtype), k_cache)
-            v_cache = jnp.where(hot, v.astype(v_cache.dtype), v_cache)
-        else:
-            k_cache = _dyn_write(k_cache, k, slot)
-            v_cache = _dyn_write(v_cache, v, slot)
+        k_cache = _stored_layout(_write_slot(k_cache, k, layer, slot))
+        v_cache = _stored_layout(_write_slot(v_cache, v, layer, slot))
 
     with jax.named_scope("attn"):
         # GQA-grouped flash-decode (the XLA twin of
@@ -292,9 +312,9 @@ def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
         hd = q.shape[-1]
         qg = q.reshape(b, kh, g, hd)
         qg = constrain(qg, ("act_batch", "act_kv", None, None))
-        kc = constrain(k_cache,
+        kc = constrain(_window(k_cache, layer, width, kh),
                        ("act_batch", "act_cache_seq", "act_kv", None))
-        vc = constrain(v_cache,
+        vc = constrain(_window(v_cache, layer, width, kh),
                        ("act_batch", "act_cache_seq", "act_kv", None))
         scores = jnp.einsum("bkgd,bskd->bkgs", qg, kc,
                             preferred_element_type=jnp.float32)
@@ -302,7 +322,7 @@ def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
         # valid entries: linear -> j <= pos (within the sliding window if
         # any); ring -> every slot written so far (the buffer IS the
         # window)
-        j = jnp.arange(cap).reshape(1, 1, 1, cap)
+        j = jnp.arange(width).reshape(1, 1, 1, width)
         pos_b = pos[:, None, None, None] if per_row else pos
         if ring:
             mask = (j < jnp.minimum(pos_b + 1, cap))
@@ -310,7 +330,7 @@ def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
             mask = (j <= pos_b)
             if cfg.sliding_window:
                 mask = mask & (j > pos_b - cfg.sliding_window)
-        scores = jnp.where(mask, scores, NEG_INF)       # (b, kh, g, cap)
+        scores = jnp.where(mask, scores, NEG_INF)       # (b, kh, g, width)
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(vc.dtype), vc)
         out = out.reshape(b, 1, cfg.n_heads, hd)
@@ -318,30 +338,40 @@ def decode_self_attention(x: jax.Array, p: Dict[str, jax.Array],
     return out, k_cache, v_cache
 
 
-
-def _dyn_write(cache: jax.Array, new: jax.Array, slot: jax.Array) -> jax.Array:
-    """Write new (B,1,K,hd) at cache[:, slot]."""
-    zero = jnp.zeros((), jnp.int32)
-    return jax.lax.dynamic_update_slice(
-        cache, new.astype(cache.dtype), (zero, slot.astype(jnp.int32), zero, zero))
+def _write_slot(cache: jax.Array, new: jax.Array, layer,
+                slot: jax.Array) -> jax.Array:
+    """Write new (B, 1, K, hd) into layer ``layer`` at ``slot``: a scalar
+    slot for every row in one ``dynamic_update_slice``, a (B,) vector of
+    per-row slots in B one-slot ones.  Either touches only the new
+    slots: neither a select over the cache nor a scatter, which XLA:CPU
+    lowers to a scalar loop."""
+    b = new.shape[0]
+    new = new.astype(cache.dtype).reshape(1, b, 1, -1)   # (1, B, 1, K*hd)
+    if jnp.ndim(slot) == 0:
+        return jax.lax.dynamic_update_slice(cache, new, (layer, 0, slot, 0))
+    for r in range(b):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[:, r:r + 1], (layer, r, slot[r], 0))
+    return cache
 
 
 def prefill_self_attention(x: jax.Array, p: Dict[str, jax.Array],
                            cfg: ModelConfig, k_cache: jax.Array,
-                           v_cache: jax.Array, start: jax.Array,
-                           window: int = 0,
+                           v_cache: jax.Array, layer, start: jax.Array,
+                           width: int, window: int = 0,
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Chunked prefill: process S new tokens starting at absolute position
-    ``start``, writing into linear caches and attending over everything
-    written so far.  Used both for prompt prefill and SpecReason's
-    verification/extension passes.
+    ``start``, writing them into layer ``layer`` of the stacked linear
+    caches (L, B, C, K*hd) and attending over slots ``[0, width)``
+    (static; the caller keeps every real token below it).  Used both for
+    prompt prefill and SpecReason's verification/extension passes.
 
     ``start`` is a scalar (all rows aligned) or a (B,) vector (ragged
     rows — the continuous-batching engine's length-bucketed extends): with
     a vector, each row's chunk is scattered at its own offset and masked
     by its own positions."""
     b, s, _ = x.shape
-    cap = k_cache.shape[1]
+    cap = k_cache.shape[2]
     per_row = jnp.ndim(start) == 1
     with jax.named_scope("attn"):
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -357,36 +387,36 @@ def prefill_self_attention(x: jax.Array, p: Dict[str, jax.Array],
             q = apply_rope(q, posv, cfg.rope_theta)
             k = apply_rope(k, posv, cfg.rope_theta)
     with jax.named_scope("kv_write"):
+        kn = k.astype(k_cache.dtype).reshape(b, s, -1)   # (B, S, K*hd)
+        vn = v.astype(v_cache.dtype).reshape(b, s, -1)
         if per_row:
-            # per-row scatter; trailing-pad writes past a row's real
-            # length are clamped into the last slot, which is harmless for
-            # the same reason trailing pads are (overwritten before it
-            # becomes visible) as long as the caller keeps real contexts
-            # below capacity (asserted by the batch engine).
+            # per-row scatter; trailing-pad writes past capacity are
+            # clamped into the last slot, which is harmless for the same
+            # reason trailing pads are (overwritten before it becomes
+            # visible) as long as the caller keeps real contexts below
+            # capacity (asserted by the batch engine).
             idx = jnp.minimum(posv, cap - 1)
             rows = jnp.arange(b)[:, None]
-            k_cache = k_cache.at[rows, idx].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[rows, idx].set(v.astype(v_cache.dtype))
+            k_cache = k_cache.at[layer, rows, idx].set(kn)
+            v_cache = v_cache.at[layer, rows, idx].set(vn)
         else:
-            zero = jnp.zeros((), jnp.int32)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype),
-                (zero, start.astype(jnp.int32), zero, zero))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype),
-                (zero, start.astype(jnp.int32), zero, zero))
+            at = (layer, 0, start.astype(jnp.int32), 0)
+            k_cache = jax.lax.dynamic_update_slice(k_cache, kn[None], at)
+            v_cache = jax.lax.dynamic_update_slice(v_cache, vn[None], at)
     with jax.named_scope("attn"):
-        if not per_row and s * cap > _BLOCKWISE_THRESHOLD:
+        kw = _window(k_cache, layer, width, cfg.n_kv_heads)
+        vw = _window(v_cache, layer, width, cfg.n_kv_heads)
+        if not per_row and s * width > _BLOCKWISE_THRESHOLD:
             # grouped-GQA blockwise path: no kv head repetition in HBM
-            out = blockwise_sdpa(q, k_cache, v_cache, start, causal=True,
+            out = blockwise_sdpa(q, kw, vw, start, causal=True,
                                  window=window)
         else:
             n_rep = cfg.n_heads // cfg.n_kv_heads
-            kf = _repeat_kv(k_cache, n_rep)
-            vf = _repeat_kv(v_cache, n_rep)
-            kj = jnp.arange(cap)
+            kf = _repeat_kv(kw, n_rep)
+            vf = _repeat_kv(vw, n_rep)
+            kj = jnp.arange(width)
             if per_row:
-                # (b, s, cap)
+                # (b, s, width)
                 mask = (kj[None, None, :] <= posv[:, :, None])
                 if window:
                     mask = mask & (kj[None, None, :]

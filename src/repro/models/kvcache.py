@@ -26,7 +26,12 @@ import jax.numpy as jnp
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class DecodeState:
-    # attention KV caches, stacked over layers: (L, B, C, K, hd)
+    # self-attention KV caches, stacked over layers: (L, B, C, K*hd).
+    # Heads and head_dim share one minor dim so the stored layout is the
+    # plain row-major one on TPU as well: with (K, hd) = (32, 96) minor,
+    # hd would pad to 128 and the compiler stores the cache slot-minor, a
+    # layout that the in-place writes and the attention read copy the
+    # whole cache out of and back into on every call.
     k: Optional[jax.Array]
     v: Optional[jax.Array]
     # mamba states: conv (L, B, W-1, ch), ssm (L, B, H, P, N)
@@ -74,12 +79,9 @@ def make_decode_state(cfg, batch: int, capacity: int, dtype=jnp.float32,
     kv = cfg.n_kv_heads
     k = v = conv = ssm = ck = cv = None
 
-    if cfg.family in ("dense", "moe", "vlm", "encdec"):
+    if cfg.has_attention:
         n_attn = cfg.n_self_layers if cfg.family == "vlm" else cfg.n_layers
-        k = jnp.zeros((n_attn, batch, capacity, kv, hd), dtype)
-        v = jnp.zeros_like(k)
-    if cfg.family == "hybrid":
-        k = jnp.zeros((cfg.n_layers, batch, capacity, kv, hd), dtype)
+        k = jnp.zeros((n_attn, batch, capacity, kv * hd), dtype)
         v = jnp.zeros_like(k)
     if cfg.has_ssm:
         ch = cfg.ssm_d_inner + 2 * cfg.ssm_n_groups * cfg.ssm_state

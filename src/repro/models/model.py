@@ -283,233 +283,155 @@ class Model:
         return dataclasses.replace(state, cross_k=ck.astype(state.cross_k.dtype),
                                    cross_v=cv.astype(state.cross_v.dtype))
 
+    # ---------------------------------------------------------- layer scan --
+    def _scan_layers(self, params, x, state: DecodeState, attend, mix
+                     ) -> Tuple[jax.Array, DecodeState]:
+        """Every layer over ``x``, threading the decode state (``pos``
+        left to the caller).  ``attend(h, p, k, v, layer)`` is the cached
+        self-attention of one layer, given the WHOLE stacked caches
+        ``(L, B, C, K*hd)``: they ride in the scan's carry, so each layer
+        writes only its new tokens' slots and the cache is never
+        restacked.  ``mix(h, p, conv, ssm) -> (y, (conv, ssm))`` is one
+        layer's Mamba mixer; its small per-layer states stay scanned
+        inputs and outputs."""
+        cfg = self.cfg
+
+        def norm(x, p):
+            return apply_norm(x, p, cfg.norm_type, cfg.rmsnorm_eps)
+
+        if cfg.family == "ssm":
+            def ssm_step(x, xs):
+                lp, conv, ssm = xs
+                y, (conv, ssm) = mix(norm(x, lp["ln1"]), lp["mixer"],
+                                     conv, ssm)
+                return x + y, (conv, ssm)
+            x, (conv, ssm) = jax.lax.scan(
+                ssm_step, x, (params["layers"], state.conv, state.ssm))
+            return x, dataclasses.replace(state, conv=conv, ssm=ssm)
+
+        if cfg.family == "vlm":
+            ng, pg = params["layers"]["attn"]["wq"].shape[:2]
+
+            def group(carry, xs):
+                g, lp_g, cp, ckl, cvl = xs
+
+                def inner(carry, ys):
+                    x, k, v = carry
+                    j, lp = ys
+                    o, k, v = attend(norm(x, lp["ln1"]), lp["attn"], k, v,
+                                     g * pg + j)
+                    x = x + o
+                    x = x + apply_mlp(norm(x, lp["ln2"]), lp["mlp"], cfg.act)
+                    return (x, k, v), None
+                (x, k, v), _ = jax.lax.scan(inner, carry,
+                                            (jnp.arange(pg), lp_g))
+                x = x + jnp.tanh(cp["gate_attn"]) * attn.cross_attention(
+                    norm(x, cp["ln1"]), None, cp["cross"], cfg,
+                    cached_kv=(ckl, cvl))
+                x = x + jnp.tanh(cp["gate_mlp"]) * apply_mlp(
+                    norm(x, cp["ln2"]), cp["mlp"], cfg.act)
+                return (x, k, v), None
+
+            (x, k, v), _ = jax.lax.scan(
+                group, (x, state.k, state.v),
+                (jnp.arange(ng), params["layers"], params["cross_layers"],
+                 state.cross_k, state.cross_v))
+            return x, dataclasses.replace(state, k=k, v=v)
+
+        # dense, moe, encdec and hybrid share one scan
+        if cfg.family == "encdec":
+            extra = (state.cross_k, state.cross_v)
+        elif cfg.family == "hybrid":
+            extra = (state.conv, state.ssm)
+        else:
+            extra = ()
+
+        def step(carry, xs):
+            x, k, v = carry
+            i, lp, extra = xs
+            h = norm(x, lp["ln1"])
+            o, k, v = attend(h, lp["attn"], k, v, i)
+            if cfg.family == "hybrid":
+                m, extra = mix(h, lp["mamba"], *extra)
+                x = x + 0.5 * (o + m)
+            else:
+                x = x + o
+            if cfg.family == "encdec":
+                x = x + attn.cross_attention(norm(x, lp["ln2"]), None,
+                                             lp["cross"], cfg,
+                                             cached_kv=extra)
+                x = x + apply_mlp(norm(x, lp["ln3"]), lp["mlp"], cfg.act)
+            elif cfg.family == "moe":
+                y, _ = moe.apply_moe(norm(x, lp["ln2"]), lp["moe"], cfg)
+                x = x + y
+            else:
+                x = x + apply_mlp(norm(x, lp["ln2"]), lp["mlp"], cfg.act)
+            return (x, k, v), extra if cfg.family == "hybrid" else None
+
+        n = state.k.shape[0]
+        (x, k, v), extra = jax.lax.scan(
+            step, (x, state.k, state.v),
+            (jnp.arange(n), params["layers"], extra))
+        new_state = dataclasses.replace(state, k=k, v=v)
+        if cfg.family == "hybrid":
+            new_state = dataclasses.replace(new_state, conv=extra[0],
+                                            ssm=extra[1])
+        return x, new_state
+
     # ----------------------------------------------------- prefill / extend --
-    def prefill(self, params, tokens, state: DecodeState
+    def prefill(self, params, tokens, state: DecodeState,
+                width: Optional[int] = None
                 ) -> Tuple[jax.Array, DecodeState]:
         """Process S tokens starting at state.pos (chunked prefill / extend).
         Returns (logits (B,S,V), new state).  Used for prompts, for
         SpecReason verification passes, and for accepting speculated steps
         into the base model's cache.  ``state.pos`` may be a scalar or a
         (B,) vector (ragged rows — continuous batching); the attention
-        layer handles per-row scatter/masking."""
+        layer handles per-row scatter/masking.  ``width`` (static, default
+        the whole capacity) is how many leading cache slots attention
+        reads; the caller keeps every real token below it."""
         cfg = self.cfg
-        b, s = tokens.shape
+        s = tokens.shape[1]
         start = state.pos
         x = self._embed(params, tokens, start)
-        if jnp.ndim(start) == 1:
-            positions = start[:, None] + jnp.arange(s)[None, :]
-        else:
-            positions = jnp.broadcast_to(start + jnp.arange(s)[None], (b, s))
-        window = cfg.sliding_window
+        width = width or state.capacity
 
-        if cfg.family == "ssm":
-            def step(x, xs):
-                lp, conv, ssm = xs
-                h = apply_norm(x, lp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                y, (nc, ns) = mamba2.apply_mamba(h, lp["mixer"], cfg,
-                                                 state=(conv, ssm),
-                                                 return_state=True)
-                return x + y, (nc, ns)
-            x, (conv, ssm) = jax.lax.scan(step, x,
-                                          (params["layers"], state.conv,
-                                           state.ssm))
-            new_state = dataclasses.replace(state, conv=conv, ssm=ssm,
-                                            pos=start + s)
-        elif cfg.family == "vlm":
-            gshape = params["layers"]["attn"]["wq"].shape[:2]
-            ng, pg = gshape
-            kc = state.k.reshape((ng, pg) + state.k.shape[1:])
-            vc = state.v.reshape((ng, pg) + state.v.shape[1:])
+        def attend(h, p, k, v, layer):
+            return attn.prefill_self_attention(h, p, cfg, k, v, layer, start,
+                                               width, cfg.sliding_window)
 
-            def group(x, xs):
-                lp_g, cp, kg, vg, ckl, cvl = xs
+        def mix(h, p, conv, ssm):
+            return mamba2.apply_mamba(h, p, cfg, state=(conv, ssm),
+                                      return_state=True)
 
-                def inner(x, ys):
-                    lp, kl, vl = ys
-                    h = apply_norm(x, lp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                    o, kl, vl = attn.prefill_self_attention(
-                        h, lp["attn"], cfg, kl, vl, start, window)
-                    x = x + o
-                    h = apply_norm(x, lp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                    x = x + apply_mlp(h, lp["mlp"], cfg.act)
-                    return x, (kl, vl)
-                x, (kg, vg) = jax.lax.scan(inner, x, (lp_g, kg, vg))
-                h = apply_norm(x, cp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                x = x + jnp.tanh(cp["gate_attn"]) * attn.cross_attention(
-                    h, None, cp["cross"], cfg, cached_kv=(ckl, cvl))
-                h = apply_norm(x, cp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                x = x + jnp.tanh(cp["gate_mlp"]) * apply_mlp(h, cp["mlp"],
-                                                             cfg.act)
-                return x, (kg, vg)
-
-            x, (kc, vc) = jax.lax.scan(group, x,
-                                       (params["layers"],
-                                        params["cross_layers"], kc, vc,
-                                        state.cross_k, state.cross_v))
-            new_state = dataclasses.replace(
-                state, k=kc.reshape(state.k.shape), v=vc.reshape(state.v.shape),
-                pos=start + s)
-        else:
-            def step(x, xs):
-                if cfg.family == "encdec":
-                    lp, kl, vl, ckl, cvl = xs
-                elif cfg.family == "hybrid":
-                    lp, kl, vl, conv, ssm = xs
-                else:
-                    lp, kl, vl = xs
-                h = apply_norm(x, lp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                o, kl, vl = attn.prefill_self_attention(
-                    h, lp["attn"], cfg, kl, vl, start, window)
-                if cfg.family == "hybrid":
-                    m, (conv, ssm) = mamba2.apply_mamba(
-                        h, lp["mamba"], cfg, state=(conv, ssm),
-                        return_state=True)
-                    x = x + 0.5 * (o + m)
-                else:
-                    x = x + o
-                if cfg.family == "encdec":
-                    h = apply_norm(x, lp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                    x = x + attn.cross_attention(h, None, lp["cross"], cfg,
-                                                 cached_kv=(ckl, cvl))
-                    h = apply_norm(x, lp["ln3"], cfg.norm_type, cfg.rmsnorm_eps)
-                    x = x + apply_mlp(h, lp["mlp"], cfg.act)
-                    return x, (kl, vl)
-                h = apply_norm(x, lp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                if cfg.family == "moe":
-                    y, _ = moe.apply_moe(h, lp["moe"], cfg)
-                    x = x + y
-                else:
-                    x = x + apply_mlp(h, lp["mlp"], cfg.act)
-                if cfg.family == "hybrid":
-                    return x, (kl, vl, conv, ssm)
-                return x, (kl, vl)
-
-            if cfg.family == "encdec":
-                xs = (params["layers"], state.k, state.v, state.cross_k,
-                      state.cross_v)
-                x, (k, v) = jax.lax.scan(step, x, xs)
-                new_state = dataclasses.replace(state, k=k, v=v, pos=start + s)
-            elif cfg.family == "hybrid":
-                xs = (params["layers"], state.k, state.v, state.conv, state.ssm)
-                x, (k, v, conv, ssm) = jax.lax.scan(step, x, xs)
-                new_state = dataclasses.replace(state, k=k, v=v, conv=conv,
-                                                ssm=ssm, pos=start + s)
-            else:
-                xs = (params["layers"], state.k, state.v)
-                x, (k, v) = jax.lax.scan(step, x, xs)
-                new_state = dataclasses.replace(state, k=k, v=v, pos=start + s)
-
+        x, new_state = self._scan_layers(params, x, state, attend, mix)
         x = apply_norm(x, params["final_norm"], cfg.norm_type, cfg.rmsnorm_eps)
-        return self._unembed(params, x), new_state
+        return (self._unembed(params, x),
+                dataclasses.replace(new_state, pos=start + s))
 
     # --------------------------------------------------------------- decode --
-    def decode_step(self, params, state: DecodeState, tokens
+    def decode_step(self, params, state: DecodeState, tokens,
+                    width: Optional[int] = None
                     ) -> Tuple[jax.Array, DecodeState]:
-        """One-token decode.  tokens: (B, 1).  Returns (logits (B,V), state)."""
+        """One-token decode.  tokens: (B, 1).  Returns (logits (B,V), state).
+        ``width`` as in :meth:`prefill` (a ring buffer takes the whole
+        capacity)."""
         cfg = self.cfg
-        b = tokens.shape[0]
         pos = state.pos
         x = self._embed(params, tokens, pos)
-        ring = state.ring
+        width = width or state.capacity
 
-        if cfg.family == "ssm":
-            def step(x, xs):
-                lp, conv, ssm = xs
-                h = apply_norm(x, lp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                y, (nc, ns) = mamba2.apply_mamba_decode(h, lp["mixer"], cfg,
-                                                        (conv, ssm))
-                return x + y, (nc, ns)
-            x, (conv, ssm) = jax.lax.scan(step, x,
-                                          (params["layers"], state.conv,
-                                           state.ssm))
-            new_state = dataclasses.replace(state, conv=conv, ssm=ssm,
-                                            pos=pos + 1)
-        elif cfg.family == "vlm":
-            ng = params["cross_layers"]["ln1"]["scale"].shape[0]
-            pg = cfg.cross_attn_every - 1
-            kc = state.k.reshape((ng, pg) + state.k.shape[1:])
-            vc = state.v.reshape((ng, pg) + state.v.shape[1:])
+        def attend(h, p, k, v, layer):
+            return attn.decode_self_attention(h, p, cfg, k, v, layer, pos,
+                                              width, ring=state.ring)
 
-            def group(x, xs):
-                lp_g, cp, kg, vg, ckl, cvl = xs
+        def mix(h, p, conv, ssm):
+            return mamba2.apply_mamba_decode(h, p, cfg, (conv, ssm))
 
-                def inner(x, ys):
-                    lp, kl, vl = ys
-                    h = apply_norm(x, lp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                    o, kl, vl = attn.decode_self_attention(
-                        h, lp["attn"], cfg, kl, vl, pos, ring=ring)
-                    x = x + o
-                    h = apply_norm(x, lp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                    x = x + apply_mlp(h, lp["mlp"], cfg.act)
-                    return x, (kl, vl)
-                x, (kg, vg) = jax.lax.scan(inner, x, (lp_g, kg, vg))
-                h = apply_norm(x, cp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                x = x + jnp.tanh(cp["gate_attn"]) * attn.cross_attention(
-                    h, None, cp["cross"], cfg, cached_kv=(ckl, cvl))
-                h = apply_norm(x, cp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                x = x + jnp.tanh(cp["gate_mlp"]) * apply_mlp(h, cp["mlp"],
-                                                             cfg.act)
-                return x, (kg, vg)
-
-            x, (kc, vc) = jax.lax.scan(group, x,
-                                       (params["layers"],
-                                        params["cross_layers"], kc, vc,
-                                        state.cross_k, state.cross_v))
-            new_state = dataclasses.replace(
-                state, k=kc.reshape(state.k.shape), v=vc.reshape(state.v.shape),
-                pos=pos + 1)
-        else:
-            def step(x, xs):
-                if cfg.family == "encdec":
-                    lp, kl, vl, ckl, cvl = xs
-                elif cfg.family == "hybrid":
-                    lp, kl, vl, conv, ssm = xs
-                else:
-                    lp, kl, vl = xs
-                h = apply_norm(x, lp["ln1"], cfg.norm_type, cfg.rmsnorm_eps)
-                o, kl, vl = attn.decode_self_attention(
-                    h, lp["attn"], cfg, kl, vl, pos, ring=ring)
-                if cfg.family == "hybrid":
-                    m, (conv, ssm) = mamba2.apply_mamba_decode(
-                        h, lp["mamba"], cfg, (conv, ssm))
-                    x = x + 0.5 * (o + m)
-                else:
-                    x = x + o
-                if cfg.family == "encdec":
-                    h = apply_norm(x, lp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                    x = x + attn.cross_attention(h, None, lp["cross"], cfg,
-                                                 cached_kv=(ckl, cvl))
-                    h = apply_norm(x, lp["ln3"], cfg.norm_type, cfg.rmsnorm_eps)
-                    x = x + apply_mlp(h, lp["mlp"], cfg.act)
-                    return x, (kl, vl)
-                h = apply_norm(x, lp["ln2"], cfg.norm_type, cfg.rmsnorm_eps)
-                if cfg.family == "moe":
-                    y, _ = moe.apply_moe(h, lp["moe"], cfg)
-                    x = x + y
-                else:
-                    x = x + apply_mlp(h, lp["mlp"], cfg.act)
-                if cfg.family == "hybrid":
-                    return x, (kl, vl, conv, ssm)
-                return x, (kl, vl)
-
-            if cfg.family == "encdec":
-                xs = (params["layers"], state.k, state.v, state.cross_k,
-                      state.cross_v)
-                x, (k, v) = jax.lax.scan(step, x, xs)
-                new_state = dataclasses.replace(state, k=k, v=v, pos=pos + 1)
-            elif cfg.family == "hybrid":
-                xs = (params["layers"], state.k, state.v, state.conv, state.ssm)
-                x, (k, v, conv, ssm) = jax.lax.scan(step, x, xs)
-                new_state = dataclasses.replace(state, k=k, v=v, conv=conv,
-                                                ssm=ssm, pos=pos + 1)
-            else:
-                xs = (params["layers"], state.k, state.v)
-                x, (k, v) = jax.lax.scan(step, x, xs)
-                new_state = dataclasses.replace(state, k=k, v=v, pos=pos + 1)
-
+        x, new_state = self._scan_layers(params, x, state, attend, mix)
         x = apply_norm(x, params["final_norm"], cfg.norm_type, cfg.rmsnorm_eps)
         logits = self._unembed(params, x)[:, 0, :]
-        return logits, new_state
+        return logits, dataclasses.replace(new_state, pos=pos + 1)
 
 
 @functools.lru_cache(maxsize=64)

@@ -30,6 +30,11 @@ models are rejected (they keep the sequential engine; see DESIGN.md).
 Rollback: rows snapshot as (pos, last_logits row) — an O(1) truncate,
 valid because attention caches mask by position.  Block-level accounting
 for these rows lives in ``serving.paged_kv`` (the scheduler owns it).
+
+In place: no snapshot holds a cache buffer, so every engine program
+donates the state it is given and ``self.state`` is replaced by the one it
+returns; each layer writes only its new tokens' slots and attends the
+first ``cap_eff`` slots (DESIGN.md §Snapshot/rollback).
 """
 
 from __future__ import annotations
@@ -57,33 +62,6 @@ class RowSnapshot:
     last_logits: np.ndarray           # (V,) float32
 
 
-def _cache_slice(full_state, cap_eff: int):
-    """The first ``cap_eff`` slots of every row's cache: the attended
-    slice one fused call works on (named ``kv_copy`` on the device)."""
-    if full_state.k is None:
-        return full_state
-    with jax.named_scope("kv_copy"):
-        return dataclasses.replace(full_state,
-                                   k=full_state.k[:, :, :cap_eff],
-                                   v=full_state.v[:, :, :cap_eff])
-
-
-def _cache_merge(full_state, state):
-    """``full_state`` with the worked slice ``state`` merged back at slot
-    0, and ``state``'s positions (``kv_copy``: without donation this
-    rewrites the whole cache)."""
-    if full_state.k is None:
-        return dataclasses.replace(full_state, pos=state.pos)
-    with jax.named_scope("kv_copy"):
-        return dataclasses.replace(
-            full_state,
-            k=jax.lax.dynamic_update_slice(full_state.k, state.k,
-                                           (0, 0, 0, 0, 0)),
-            v=jax.lax.dynamic_update_slice(full_state.v, state.v,
-                                           (0, 0, 0, 0, 0)),
-            pos=state.pos)
-
-
 class BatchEngine:
     """One model, ``batch`` independent ragged rows over a single batched
     DecodeState.
@@ -97,7 +75,9 @@ class BatchEngine:
     Engine session (greedy and sampled) — the foundation of every
     scheduler-level token-identity guarantee.  Rollback is O(1) per row
     (`snapshot_row`/`restore_row`/`truncate_row`); block-level accounting
-    lives with the caller in ``serving.paged_kv``."""
+    lives with the caller in ``serving.paged_kv``.  Every jitted call
+    donates ``self.state``: a reference to an earlier state's ``k``/``v``
+    is dead after the next call."""
 
     def __init__(self, model: Model, params, batch: int,
                  capacity: int = 1024,
@@ -158,8 +138,7 @@ class BatchEngine:
         # moved); zero for cache-less models
         k = self.state.k
         self._kv_token_bytes = 0 if k is None else (
-            int(k.shape[0]) * 2 * int(k.shape[3]) * int(k.shape[4])
-            * k.dtype.itemsize)
+            int(k.shape[0]) * 2 * int(k.shape[3]) * k.dtype.itemsize)
         vocab = model.cfg.vocab_size
         self.pos = np.zeros(batch, np.int64)          # host mirror of pos
         self.last_logits = np.zeros((batch, vocab), np.float32)
@@ -278,19 +257,18 @@ class BatchEngine:
             return fn(*args)
 
     def _prefill_fn(self, cap_eff: int) -> Callable:
-        """Batched prefill on a ``cap_eff``-slot cache slice (merged back
-        afterwards) — same occupied-prefix discipline as the decode loop."""
+        """Batched prefill attending the first ``cap_eff`` cache slots —
+        same occupied-prefix discipline as the decode loop.  The state is
+        donated (see the class docstring)."""
         fn = self._prefill_cache.get(cap_eff)
         if fn is not None:
             return fn
         model = self.model
 
-        def prefill(params, tokens, full_state):
-            logits, state = model.prefill(params, tokens,
-                                          _cache_slice(full_state, cap_eff))
-            return logits, _cache_merge(full_state, state)
+        def prefill(params, tokens, state):
+            return model.prefill(params, tokens, state, width=cap_eff)
 
-        fn = jax.jit(prefill)
+        fn = jax.jit(prefill, donate_argnums=2)
         self._prefill_cache[cap_eff] = fn
         return fn
 
@@ -402,7 +380,7 @@ class BatchEngine:
 
     def _cap_bucket(self, n: int) -> int:
         """Smallest power-of-two (capped at capacity) covering n context
-        slots — the attended-cache slice width for one fused decode call.
+        slots — the attended window (leading cache slots) of one call.
         Attending only the occupied prefix is the XLA analog of the paged
         kernel's block-table skip: per-token HBM traffic scales with the
         *live* context, not the provisioned capacity."""
@@ -416,8 +394,8 @@ class BatchEngine:
         """The fused multi-sequence decode step: one ``jax.lax.while_loop``
         advances every active row — per-row sample, per-row stop/budget
         flags, per-row key splits — with a single dispatch and a single
-        host sync for the whole batched step.  The loop runs on a
-        ``cap_eff``-slot slice of the KV cache (merged back afterwards).
+        host sync for the whole batched step.  Attention reads the first
+        ``cap_eff`` cache slots; the state is donated.
         With ``collect_probs`` the per-step post-adjustment sampling
         distributions land in a (B, buf, V) buffer — the proposal
         distributions batched speculative decoding verifies against."""
@@ -429,9 +407,8 @@ class BatchEngine:
         pad_id = self.pad_id
         batch = self.batch
 
-        def fused(params, full_state, last_logits, keys, stop_arr,
-                  stop_mask, n_max, greedy_row):
-            state = _cache_slice(full_state, cap_eff)
+        def fused(params, state, last_logits, keys, stop_arr, stop_mask,
+                  n_max, greedy_row):
             toks0 = jnp.full((batch, buf), -1, jnp.int32)
             vocab = last_logits.shape[-1]
             probs0 = (jnp.zeros((batch, buf, vocab), jnp.float32)
@@ -467,7 +444,7 @@ class BatchEngine:
                               & stop_mask, axis=-1)
                 old_pos = state.pos
                 new_logits, new_state = model.decode_step(
-                    params, state, tok[:, None])
+                    params, state, tok[:, None], width=cap_eff)
                 # inactive rows fed a pad: keep their position (the pad's
                 # cache write landed beyond it — masked until overwritten)
                 new_state = dataclasses.replace(
@@ -482,9 +459,9 @@ class BatchEngine:
                     last_logits, keys, toks0, probs0)
             _, _, n, state, logits, _, toks, probs = jax.lax.while_loop(
                 cond, body, init)
-            return toks, n, logits, _cache_merge(full_state, state), probs
+            return toks, n, logits, state, probs
 
-        fn = jax.jit(fused)
+        fn = jax.jit(fused, donate_argnums=1)
         self._fused_cache[cache_key] = fn
         return fn
 
@@ -600,8 +577,9 @@ class BatchEngine:
     def kv_dims(self) -> Tuple[int, int, int]:
         """(n_layers, kv_heads, head_dim) of the attention cache — the
         page dimensions a PrefixKVStore for this engine needs."""
-        ll, _, _, kh, hd = self.state.k.shape
-        return ll, kh, hd
+        ll, _, _, f = self.state.k.shape
+        kh = self.model.cfg.n_kv_heads
+        return ll, kh, f // kh
 
     def export_prefix(self, row: int, start: int, end: int
                       ) -> Tuple[jax.Array, jax.Array]:
@@ -612,8 +590,9 @@ class BatchEngine:
         assert 0 <= start <= end <= self.pos[row], \
             f"row {row}: export [{start}, {end}) outside prefilled " \
             f"[0, {self.pos[row]})"
-        return (self.state.k[:, row, start:end],
-                self.state.v[:, row, start:end])
+        shape = (self.state.k.shape[0], end - start) + self.kv_dims()[1:]
+        return (self.state.k[:, row, start:end].reshape(shape),
+                self.state.v[:, row, start:end].reshape(shape))
 
     def load_prefix(self, row: int, k: jax.Array, v: jax.Array) -> None:
         """Seed a FRESH row's cache with ``n`` tokens of precomputed KV
@@ -626,14 +605,14 @@ class BatchEngine:
         assert self._live[row], f"load into dead row {row}"
         assert self.pos[row] == 0, \
             f"load_prefix onto non-fresh row {row} at pos {self.pos[row]}"
-        n = k.shape[1]
+        ll, n = k.shape[:2]
         assert 0 < n <= self.capacity
         self.state = dataclasses.replace(
             self.state,
             k=self.state.k.at[:, row, :n].set(
-                k.astype(self.state.k.dtype)),
+                k.reshape(ll, n, -1).astype(self.state.k.dtype)),
             v=self.state.v.at[:, row, :n].set(
-                v.astype(self.state.v.dtype)))
+                v.reshape(ll, n, -1).astype(self.state.v.dtype)))
         self.pos[row] = n
 
     def _import_fn(self, shape: Tuple[int, int]) -> Callable:
@@ -649,8 +628,8 @@ class BatchEngine:
             kg = k_pages[:, slots]            # (L, R, nb, bs, kv, hd)
             vg = v_pages[:, slots]
             ll, _, _, bs, kh, hd = kg.shape
-            kg = kg.reshape(ll, n_rows, nb * bs, kh, hd)
-            vg = vg.reshape(ll, n_rows, nb * bs, kh, hd)
+            kg = kg.reshape(ll, n_rows, nb * bs, kh * hd)
+            vg = vg.reshape(ll, n_rows, nb * bs, kh * hd)
             k_cache = k_cache.at[:, rows, :nb * bs].set(
                 kg.astype(k_cache.dtype))
             v_cache = v_cache.at[:, rows, :nb * bs].set(
@@ -658,10 +637,10 @@ class BatchEngine:
             return k_cache, v_cache
 
         # donating the caches makes the seed an in-place page write, not
-        # a full-cache copy.  Safe HERE (unlike the model jits, see
-        # DESIGN.md §Snapshot/rollback): BatchEngine holds exactly one
-        # live state, RowSnapshots carry no tensor references, and the
-        # caller replaces self.state with the result immediately.
+        # a full-cache copy, as in every engine program (DESIGN.md
+        # §Snapshot/rollback): BatchEngine holds exactly one live state,
+        # RowSnapshots carry no tensor references, and the caller
+        # replaces self.state with the result immediately.
         fn = jax.jit(imp, donate_argnums=(0, 1))
         self._import_cache[shape] = fn
         return fn
@@ -727,18 +706,17 @@ class BatchEngine:
             return fn
         model = self.model
 
-        def feed(params, full_state, toks, active):
-            state = _cache_slice(full_state, cap_eff)
+        def feed(params, state, toks, active):
             old_pos = state.pos
             logits, new_state = model.decode_step(params, state,
-                                                  toks[:, None])
+                                                  toks[:, None],
+                                                  width=cap_eff)
             # uninvolved rows fed a pad: keep their position (the pad's
             # cache write landed beyond it — masked until overwritten)
-            new_state = dataclasses.replace(
+            return logits, dataclasses.replace(
                 new_state, pos=jnp.where(active, old_pos + 1, old_pos))
-            return logits, _cache_merge(full_state, new_state)
 
-        fn = jax.jit(feed)
+        fn = jax.jit(feed, donate_argnums=1)
         self._feed_cache[cap_eff] = fn
         return fn
 

@@ -15,7 +15,7 @@ their contracting dims — so the compiler finishes those dots with an
 all-reduce: bitwise on the toy configs of tests/test_tp_serving.py at
 tp=2, float-close (not bitwise) at phi3 widths.
 
-KV layout: the batched decode state (L, B, capacity, kv_heads, hd) and
+KV layout: the batched decode state (L, B, capacity, kv_heads*hd) and
 every page store shard on the kv-heads dim; block tables, free lists and
 refcounts stay replicated HOST state (tp-invariant by construction —
 property-tested in tests/test_tp_pool_props.py).
@@ -84,9 +84,11 @@ class TPContext:
             params, specs)
 
     def shard_state(self, state):
-        """Commit a batched DecodeState: K/V (L, B, cap, kv, hd) sharded
-        on the kv-heads dim, position vector replicated."""
-        kv = NamedSharding(self.mesh, P(None, None, None, self.axis, None))
+        """Commit a batched DecodeState: K/V (L, B, cap, kv*hd) sharded
+        on the kv-heads-major minor dim (whole heads per device, as
+        ``check_model`` requires kv % tp == 0), position vector
+        replicated."""
+        kv = NamedSharding(self.mesh, P(None, None, None, self.axis))
         return dataclasses.replace(
             state,
             k=None if state.k is None else jax.device_put(state.k, kv),

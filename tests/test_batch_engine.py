@@ -2,6 +2,8 @@
 sequential engine token-for-token (greedy AND sampled), isolate rows from
 each other, and honor per-row budgets/stops/keys."""
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -187,3 +189,67 @@ def test_row_reuse_after_free():
     s = eng.extend(eng.new_session(), PROMPTS[1])
     ids, _, _ = eng.generate_fused(s, 8, [], sp, jax.random.PRNGKey(1))
     assert out[0] == ids
+
+
+def test_engine_calls_donate_the_cache():
+    """extend_rows, generate_rows and feed_rows donate the state they are
+    given: the previous K/V buffers are gone after each call, and the
+    prefix-cache calls that read and write ``be.state`` between calls see
+    the current one."""
+    m, params = _mk()
+    be = BatchEngine(m, params, batch=3, capacity=64)
+    sp = SamplingParams(temperature=0.0)
+    a, b = be.alloc_row(), be.alloc_row()
+
+    def donated(call):
+        k, v = be.state.k, be.state.v
+        call()
+        assert k.is_deleted() and v.is_deleted()
+        assert not (be.state.k.is_deleted() or be.state.v.is_deleted())
+
+    donated(lambda: be.extend_rows([a], [PROMPTS[1]]))
+    donated(lambda: be.generate_rows([a], 4, [], sp,
+                                     [jax.random.PRNGKey(0)]))
+    donated(lambda: be.feed_rows([a], [tk.STEP]))
+    # export the row as it stands, seed a fresh row with it, and the two
+    # continue alike
+    n = int(be.pos[a])
+    k, v = be.export_prefix(a, 0, n)
+    be.load_prefix(b, k, v)
+    donated(lambda: be.extend_rows([a, b], [[tk.STEP], [tk.STEP]]))
+    np.testing.assert_allclose(be.last_logits[b], be.last_logits[a],
+                               rtol=1e-5, atol=1e-5)
+    c = be.alloc_row()
+    be.load_prefix_pages_rows([c], k[:, None], v[:, None], [[0]])
+    assert be.pos[c] == n
+    donated(lambda: be.feed_rows([c], [tk.STEP]))
+    # a decode step against the prefill's extend: same context, other
+    # arithmetic order
+    np.testing.assert_allclose(be.last_logits[c], be.last_logits[a],
+                               rtol=1e-4, atol=1e-4)
+
+
+def _copies_of(text, shape):
+    """HLO instructions of a compiled program that copy a ``shape``d
+    buffer (``f32[2,4,64,2,16]``-style shape strings)."""
+    return [ln for ln in text.splitlines()
+            if re.search(r"= " + re.escape(shape) + r"\{[^}]*\} copy\(", ln)]
+
+
+def test_extend_program_updates_the_cache_in_place():
+    """The compiled extend aliases the cache input to its output and
+    copies no buffer of the whole cache's shape (no restack of the layer
+    scan, no slice merged back)."""
+    m, params = _mk()
+    be = BatchEngine(m, params, batch=4, capacity=CAP)
+    toks = jax.numpy.zeros((be.batch, 8), jax.numpy.int32)
+    text = be._prefill_fn(64).lower(be.params, toks,
+                                    be.state).compile().as_text()
+    header = text.splitlines()[0]
+    n_in = len(jax.tree_util.tree_leaves((be.params, toks)))
+    # outputs 1 and 2 are the new K and V, inputs n_in, n_in + 1 the old
+    assert re.search(r"\{1\}: \(%d, \{\}" % n_in, header), header
+    assert re.search(r"\{2\}: \(%d, \{\}" % (n_in + 1), header), header
+    shape = "f32[%s]" % ",".join(str(d) for d in be.state.k.shape)
+    assert shape in text
+    assert _copies_of(text, shape) == []

@@ -3,6 +3,9 @@ assigned architecture's family runs one forward + one train step on CPU,
 asserting output shapes and no NaNs — plus the strong consistency property
 forward == prefill+decode for every family."""
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -158,3 +161,61 @@ def test_blockwise_gqa_matches_direct_sdpa():
     exp_w = attn.sdpa(q, kf, vf, attn.causal_mask(sq, sq, window=24))
     np.testing.assert_allclose(np.asarray(out_w), np.asarray(exp_w),
                                rtol=2e-5, atol=2e-5)
+
+
+def _window_case(kind):
+    """(model, params, state, tokens, width) for one window case: a
+    small dense model, a cache of 32 slots filled with noise, ragged
+    per-row positions or one shared one."""
+    from repro.models.config import ModelConfig
+    cfg = ModelConfig(name="window", family="dense", n_layers=2, d_model=32,
+                      n_heads=4, n_kv_heads=2, head_dim=8, d_ff=64,
+                      vocab_size=48).validate()
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(11))
+    b, cap = 3, 32
+    st = model.init_state(b, cap)
+    kk, kv = jax.random.split(jax.random.PRNGKey(12))
+    st = dataclasses.replace(
+        st, k=jax.random.normal(kk, st.k.shape),
+        v=jax.random.normal(kv, st.v.shape))
+    s = 1 if kind.startswith("decode") else 6
+    # ragged: rows at 0, 5 and 9 of a 16-slot window, so the chunks'
+    # trailing pads land inside it; "cap": the window is the whole cache
+    # and the last row's pads run past it, clamped onto its last slot
+    pos, width = {"prefill_ragged": ([0, 5, 9], 16),
+                  "prefill_cap": ([0, 7, cap - 3], cap),
+                  "prefill_shared": (4, 16),
+                  "decode_ragged": ([0, 5, 15], 16),
+                  "decode_shared": (11, 16)}[kind]
+    st = dataclasses.replace(st, pos=jnp.asarray(pos, jnp.int32))
+    toks = jax.random.randint(jax.random.PRNGKey(13), (b, s), 0,
+                              cfg.vocab_size)
+    return model, params, st, toks, width
+
+
+@pytest.mark.parametrize("kind", ["prefill_ragged", "prefill_cap",
+                                  "prefill_shared", "decode_ragged",
+                                  "decode_shared"])
+def test_window_matches_slice_run_merge(kind):
+    """Attending the first ``width`` slots of the whole cache, written in
+    place, gives the logits and cache of slicing the cache to ``width``,
+    running on the slice and merging it back."""
+    model, params, st, toks, width = _window_case(kind)
+
+    @functools.partial(jax.jit, static_argnames="width")
+    def run(state, width=None):
+        if kind.startswith("decode"):
+            return model.decode_step(params, state, toks, width=width)
+        return model.prefill(params, toks, state, width=width)
+
+    lg, new = run(st, width=width)
+    lg_ref, ref = run(dataclasses.replace(st, k=st.k[:, :, :width],
+                                          v=st.v[:, :, :width]))
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lg_ref),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(new.k), np.asarray(st.k.at[:, :, :width].set(ref.k)))
+    np.testing.assert_array_equal(
+        np.asarray(new.v), np.asarray(st.v.at[:, :, :width].set(ref.v)))
+    np.testing.assert_array_equal(np.asarray(new.pos), np.asarray(ref.pos))

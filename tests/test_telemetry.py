@@ -317,7 +317,7 @@ def test_engine_programs_carry_device_scopes(engine_pair):
         debug_info=True)
     paths = {part for loc in re.findall(r'loc\("([^"]*)"', text)
              for part in loc.split("/")}
-    assert {"attn", "kv_write", "kv_copy", "mlp", "lm_head"} <= paths
+    assert {"attn", "kv_write", "mlp", "lm_head"} <= paths
 
 
 def test_snapshot_rows_count_committed_tokens(engine_pair):
