@@ -7,6 +7,10 @@ Everything that belongs to one configuration, traffic mix, cell or
 metric is a file of its own, found by the names in ``BENCHMARK.json``:
 
   configs/<config>.json     sizes as run, source, cuts, drafter recipe
+  architectures/<arch>.py   what a configuration's architecture owns: its
+                            sizes, program config, parameter layout,
+                            drafter, FLOP count and device scopes (the
+                            names in ``ARCH_NAMES``)
   references/<ref>.py       the plain reference a configuration names
   traffic/<mix>.json        parameters of the one generator (arrivals.py)
   cells/<workload>.json     serving settings of one cell and its limits
@@ -20,9 +24,11 @@ import gc
 import importlib.util
 import json
 import math
+import sys
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Sequence
+from types import ModuleType
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -32,6 +38,10 @@ import weights
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+ARCHITECTURES = HERE / "architectures"
+# what the harness, the check and the readers call in an architecture
+ARCH_NAMES = ("dims", "model_config", "layout", "drafter_params",
+              "tokens_flops", "SCOPES")
 
 # The toy tokenizer's protocol ids the reference check needs (asserted
 # against the program's tokenizer when the program is built).
@@ -48,6 +58,7 @@ def load_json(path: Path):
 def load_module(path: Path, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod          # dataclasses look their module up
     spec.loader.exec_module(mod)
     return mod
 
@@ -59,17 +70,39 @@ class Cell:
     name: str
     chips: int
     config: Dict
+    arch: ModuleType
     mix: Dict
     serve: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
 
     @property
-    def dims(self) -> weights.Dims:
-        return weights.Dims.from_config(self.config)
+    def dims(self):
+        return self.arch.dims(self.config)
 
 
-def load_cell(workload: str, root: Path = ROOT) -> Cell:
+def load_config(path: Path, architectures: Path = ARCHITECTURES
+                ) -> Tuple[Dict, ModuleType]:
+    """A configuration file and the architecture module it names
+    (``"architecture": "<arch>"`` loads ``<architectures>/<arch>.py``)."""
+    config = load_json(path)
+    if "architecture" not in config:
+        raise SystemExit(f"{path}: no \"architecture\" key; name the "
+                         f"module under {architectures} that serves it")
+    arch = config["architecture"]
+    mod_path = Path(architectures) / f"{arch}.py"
+    if not mod_path.is_file():
+        raise SystemExit(f"{path}: architecture {arch!r} has no module "
+                         f"{mod_path}")
+    mod = load_module(mod_path, f"chipbench_architecture_{arch}")
+    missing = [n for n in ARCH_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"{mod_path} (named by {path}) lacks {missing}")
+    return config, mod
+
+
+def load_cell(workload: str, root: Path = ROOT,
+              architectures: Path = ARCHITECTURES) -> Cell:
     bench = load_json(root / "BENCHMARK.json")
     by_name = {w["name"]: w for w in bench["workloads"]}
     if workload not in by_name:
@@ -82,8 +115,9 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     def applies(m):
         return "workloads" not in m or workload in m["workloads"]
 
-    return Cell(name=workload, chips=entry["chips"],
-                config=load_json(root / cfg_file),
+    config, arch = load_config(root / cfg_file, architectures)
+    return Cell(name=workload, chips=entry["chips"], config=config,
+                arch=arch,
                 mix=load_json(HERE / "traffic" / f"{entry['traffic']}.json"),
                 serve=load_json(HERE / "cells" / f"{workload}.json"),
                 end_to_end=[m for m in bench["end_to_end"] if applies(m)],
@@ -101,18 +135,6 @@ def reference(config: Dict):
 
 
 # ---------------------------------------------------------- the program
-
-def model_config(dims: weights.Dims, n_layers: Optional[int] = None,
-                 suffix: str = ""):
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=dims.name + suffix, family="dense",
-        n_layers=n_layers or dims.n_layers, d_model=dims.d_model,
-        n_heads=dims.n_heads, n_kv_heads=dims.n_kv_heads,
-        head_dim=dims.head_dim, d_ff=dims.d_ff, vocab_size=dims.vocab,
-        rmsnorm_eps=dims.eps, norm_type=dims.norm, act=dims.act,
-        rope_theta=dims.rope_theta, sliding_window=dims.window).validate()
-
 
 class PinnedAcceptance:
     """Step acceptance pinned to a share: random weights make the
@@ -145,10 +167,18 @@ class PinnedAcceptance:
 WEIGHTS_SEED = 0x5EED
 
 
+def draw_params(cell: Cell) -> Dict:
+    """The cell's weights, drawn on the device from ``WEIGHTS_SEED`` in
+    its architecture's layout."""
+    dims = cell.dims
+    return weights.make_params(cell.arch.layout(dims), dims.jnp_dtype,
+                               WEIGHTS_SEED)
+
+
 def reference_params(rec: "Rec") -> Dict:
     """The reference's own draw of the weights, once the program's are
     freed: the same seed, nothing taken from the program."""
-    return weights.make_params(rec.dims, WEIGHTS_SEED)
+    return draw_params(rec.cell)
 
 
 @dataclasses.dataclass
@@ -168,7 +198,7 @@ def _check_protocol() -> None:
 
 
 def build(cell: Cell, seed: int, tracer=None) -> Program:
-    """The system under test: seeded weights, the early-exit drafter,
+    """The system under test: seeded weights, the architecture's drafter,
     SpecReason with token-level spec decode, one ContinuousScheduler."""
     from repro.core.controller import SpecReason, SpecReasonConfig
     from repro.models.model import Model
@@ -178,18 +208,18 @@ def build(cell: Cell, seed: int, tracer=None) -> Program:
     from repro.serving.scheduler import ContinuousScheduler
 
     _check_protocol()
-    dims, sv, mix = cell.dims, cell.serve, cell.mix
-    params = weights.make_params(dims, WEIGHTS_SEED)
-    mcfg = model_config(dims)
+    arch, dims, sv, mix = cell.arch, cell.dims, cell.serve, cell.mix
+    params = draw_params(cell)
+    mcfg = arch.model_config(dims)
     want = jax.tree.map(lambda s: (s.shape, str(s.dtype)),
                         Model(mcfg).abstract(dims.jnp_dtype))
     got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), params)
     if want != got:
-        raise RuntimeError("weights.layout does not match the program's "
-                           "parameter tree")
+        raise RuntimeError(f"{arch.__file__}: layout does not match the "
+                           f"program's parameter tree")
     n_draft = cell.config["drafter"]["num_hidden_layers"]
-    dcfg = model_config(dims, n_draft, "-draft")
-    dparams = weights.drafter_params(params, n_draft)
+    dcfg = arch.model_config(dims, n_draft, "-draft")
+    dparams = arch.drafter_params(params, n_draft)
     slots = sv["slots"]
     base = Engine(Model(mcfg), params, max_len=slots, name=mcfg.name)
     small = Engine(Model(dcfg), dparams, max_len=slots, name=dcfg.name)
@@ -253,7 +283,7 @@ class Rec:
         return self.t_end - self.t_start
 
     @property
-    def dims(self) -> weights.Dims:
+    def dims(self):
         return self.cell.dims
 
     # -- requests by what the window did with them

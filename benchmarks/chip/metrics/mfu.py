@@ -1,12 +1,13 @@
 """mfu: the forward FLOPs vanilla base decoding would spend on the output
-tokens committed in the window (flops.py, from the shapes), over the
-window times the chip's bf16 peak (peaks.py)."""
+tokens committed in the window (the architecture module's
+``tokens_flops``, from the shapes), over the window times the chip's bf16
+peak (peaks.py)."""
 
-import flops
 import peaks
 
 
 def read(rec):
+    flops = rec.cell.arch.tokens_flops
     total = 0
     for r in rec.reqs:
         if r.handle is None:
@@ -14,7 +15,7 @@ def read(rec):
         a = rec.tokens_at(r, rec.snap_start, rec.t_start)
         b = rec.tokens_at(r, rec.snap_end, rec.t_end)
         p = r.spec.prompt_len
-        total += flops.tokens_flops(rec.dims, p + round(a), p + round(b))
+        total += flops(rec.dims, p + round(a), p + round(b))
     if not total:
         return None
     return 100.0 * total / (rec.window_s

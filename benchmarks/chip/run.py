@@ -39,7 +39,10 @@ import jax  # noqa: E402
 import check  # noqa: E402
 import harness  # noqa: E402
 import peaks  # noqa: E402
+import spans  # noqa: E402
 import xplane  # noqa: E402
+
+BREAKDOWN_TOP = 10
 
 
 def use_compile_cache() -> str:
@@ -90,7 +93,10 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         rec, prog = harness.run_window(cell, seed, seconds, trace, t_proc,
                                        tdir, log)
         if trace:
-            rec.trace = xplane.reduce(xplane.planes(xplane.find(tdir)))
+            pl = spans.planes(xplane.find(tdir))
+            rec.trace = xplane.reduce(pl)
+            if rec.trace is not None:
+                rec.trace.update(spans.reduce(pl, cell.arch.SCOPES))
     finally:
         if tdir:
             shutil.rmtree(tdir, ignore_errors=True)
@@ -110,8 +116,10 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
     if trace and rec.trace is not None:
         dev["busy_s"] = rec.trace["busy_s"]
         dev["window_s"] = rec.trace["window_s"]
-        result["breakdown"] = {"device_ops": rec.trace["device_ops"],
-                               "idle_gaps": rec.trace["idle_gaps"]}
+        result["breakdown"] = {
+            k: (rec.trace[k] or [])[:BREAKDOWN_TOP]
+            for k in ("device_ops", "idle_gaps", "idle_by_region",
+                      "device_by_scope")}
     # the check: after the window, with the program's state freed and the
     # reference's weights drawn anew from the seed
     waited = harness.settle(prog, rec, cell.serve["check_requests"])

@@ -12,7 +12,8 @@ layers, beside ``xplane.py``'s device totals:
   device_by_scope  device seconds of leaf operations (an "XLA Ops" event
                    that contains no other, so a loop is not counted
                    beside its body) inside the window, by the first of
-                   ``SCOPES`` in the op's name-scope path (its HLO
+                   the cell's scopes (its architecture module's
+                   ``SCOPES``) in the op's name-scope path (its HLO
                    ``op_name``); the rest reads ``other``.
 
 Each part is None for a trace with no program region or no scoped op (a
@@ -28,13 +29,12 @@ import bisect
 import mmap
 import re
 from collections import defaultdict
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import xplane
 
-SCOPES = ("attn", "kv_write", "kv_copy", "mlp", "lm_head")
 REGION = re.compile(r"(sched|spec|base|draft)(\.[a-z_]+)+$")
 OUTSIDE = "outside program"
 OTHER = "other"
@@ -143,8 +143,9 @@ INSTRUCTION = re.compile(r"%?([^\s=]+)")
 
 
 def planes(path: str) -> xplane.Planes:
-    """``xplane.planes`` with a fourth element on every "XLA Ops" event:
-    its HLO ``op_name`` ("" where the trace holds none)."""
+    """The trace as plain data (``xplane.Planes``), with a fourth element
+    on every "XLA Ops" event: its HLO ``op_name`` ("" where the trace
+    holds none)."""
     import jax
     with open(path, "rb") as fh:
         raw = mmap.mmap(fh.fileno(), 0, access=mmap.ACCESS_READ)
@@ -182,22 +183,23 @@ def planes(path: str) -> xplane.Planes:
 
 # ------------------------------------------------------------ reduction
 
-def scope_of(op_name: str) -> str:
-    """The first of ``SCOPES`` in an ``op_name`` path (``a/b/c``), else
+def scope_of(op_name: str, scopes: Sequence[str]) -> str:
+    """The first of ``scopes`` in an ``op_name`` path (``a/b/c``), else
     ``other``."""
     for part in op_name.split("/"):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return OTHER
 
 
-def _leaf_time(evs: list, w0: float, w1: float) -> Dict[str, float]:
+def _leaf_time(evs: list, w0: float, w1: float,
+               scopes: Sequence[str]) -> Dict[str, float]:
     """Seconds inside [w0, w1) of the events that contain no other event
     of their line, by scope.  Events of one line nest."""
-    kinds = SCOPES + (OTHER,)
+    kinds = tuple(scopes) + (OTHER,)
     code: Dict[str, int] = {}
     c = np.array([code[e[3]] if e[3] in code else
-                  code.setdefault(e[3], kinds.index(scope_of(e[3])))
+                  code.setdefault(e[3], kinds.index(scope_of(e[3], scopes)))
                   for e in evs])
     s = np.array([e[1] for e in evs])
     d = np.array([e[2] for e in evs])
@@ -210,10 +212,10 @@ def _leaf_time(evs: list, w0: float, w1: float) -> Dict[str, float]:
     return {k: float(v) * 1e-9 for k, v in zip(kinds, t) if v > 0}
 
 
-def reduce(pl: xplane.Planes) -> Optional[Dict]:
-    """``idle_by_region`` and ``device_by_scope`` of the traced window,
-    each as [name, seconds] pairs, largest first; None when no device
-    ran."""
+def reduce(pl: xplane.Planes, scopes: Sequence[str]) -> Optional[Dict]:
+    """``idle_by_region`` and ``device_by_scope`` (by ``scopes``) of the
+    traced window, each as [name, seconds] pairs, largest first; None
+    when no device ran."""
     found = xplane._window_line(pl)
     devices = [lines[xplane.OPS_LINE] for n, lines in pl.items()
                if xplane._is_device(n) and lines.get(xplane.OPS_LINE)]
@@ -228,7 +230,7 @@ def reduce(pl: xplane.Planes) -> Optional[Dict]:
         edges = [w0] + [x for ab in xplane._merge(iv) for x in ab] + [w1]
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                  if edges[i + 1] > edges[i]]
-        for k, v in _leaf_time(evs, w0, w1).items():
+        for k, v in _leaf_time(evs, w0, w1, scopes).items():
             scoped[k] += v
     regions = [ev for ev in host if REGION.match(ev[0])]
     idle = None

@@ -13,8 +13,8 @@ HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 
 import arrivals  # noqa: E402
-import flops  # noqa: E402
 import harness  # noqa: E402
+from architectures import dense  # noqa: E402
 
 
 def handle(rid, status=None, first=None, finished=None, admitted=None,
@@ -102,13 +102,13 @@ def test_phi3_flops_by_hand():
     dims = harness.load_cell("phi3-think1k-backlog").dims
     d, ff, v, layers = 3072, 8192, 32064, 32
     per_layer = 4 * d * d + 3 * d * ff          # q, k, v, o; gate, up, down
-    assert flops.matmul_params(dims) == layers * per_layer + d * v
+    assert dense.matmul_params(dims) == layers * per_layer + d * v
     # one token at position 999 (1000 keys, inside the 2047 window)
     want = 2 * (layers * per_layer + d * v) + 4 * layers * 32 * 96 * 1000
-    assert flops.tokens_flops(dims, 999, 1000) == want
+    assert dense.tokens_flops(dims, 999, 1000) == want
     assert want == pytest.approx(7.84e9, rel=0.01)
     # past the window every token sees 2047 keys
-    assert flops.tokens_flops(dims, 3000, 3001) - flops.tokens_flops(
+    assert dense.tokens_flops(dims, 3000, 3001) - dense.tokens_flops(
         dims, 2999, 3000) == 0
 
 
@@ -116,6 +116,6 @@ def test_mfu_reads_committed_tokens():
     a = handle("a", finished=15.0, first=5.0, out=48, steps=2)
     r = rec([req(0, a, ops=10)], start_active=[("a", 1)])
     # 24 tokens at positions 47 + 24 .. 47 + 48 in 10 s on 197 TFLOP/s
-    f = flops.tokens_flops(r.dims, 47 + 24, 47 + 48)
+    f = dense.tokens_flops(r.dims, 47 + 24, 47 + 48)
     assert harness.reader("mfu")(r) == pytest.approx(
         100 * f / (10 * 197e12))

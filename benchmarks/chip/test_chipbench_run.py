@@ -1,7 +1,10 @@
 """A whole run of a tiny cell on the CPU, past the look for a chip: the
 sound program is judged correct, the float8 control and a program that
-alters the tokens it produces are judged not correct."""
+alters the tokens it produces are judged not correct, and an
+architecture added as new files only runs to correct."""
 
+import hashlib
+import json
 import sys
 import time
 from pathlib import Path
@@ -20,11 +23,14 @@ SEED = 2**31 + 11
 WINDOW_S = 12.0
 
 
-def tiny_cell(config: str = "tiny.json") -> harness.Cell:
+def tiny_cell(config: str = "tiny.json",
+              directory: Path = HERE / "testdata",
+              architectures: Path = harness.ARCHITECTURES) -> harness.Cell:
     t = HERE / "testdata"
     e2e = [{"name": n, "unit": u} for n, u in
            (("setup_s", "s"), ("ttft_p90_ms", "ms"), ("tpot_p90_ms", "ms"))]
-    return harness.Cell("tiny", 1, harness.load_json(t / config),
+    cfg, arch = harness.load_config(directory / config, architectures)
+    return harness.Cell("tiny", 1, cfg, arch,
                         harness.load_json(t / "tiny-mix.json"),
                         harness.load_json(t / "tiny-cell.json"), e2e, [])
 
@@ -32,7 +38,7 @@ def tiny_cell(config: str = "tiny.json") -> harness.Cell:
 @pytest.mark.parametrize("config", ["tiny.json", "tiny-gelu.json"])
 def test_sound_run_is_correct(config):
     """SwiGLU/RMSNorm/MHA-like, and GELU/LayerNorm/GQA with a binding
-    sliding window (the starcoder2 path)."""
+    sliding window."""
     res = run.run(tiny_cell(config), SEED, WINDOW_S, False, time.perf_counter(),
                   require_chip=False)
     assert res["correct"] is True
@@ -85,3 +91,40 @@ def test_altered_token_is_not_correct(monkeypatch, where):
     res = run.run(tiny_cell(), SEED, WINDOW_S, False, time.perf_counter(),
                   require_chip=False)
     assert res["correct"] is False
+
+
+# an architecture that only renames the dense one's device scopes
+RENAMED = """from architectures.dense import *  # noqa: F401,F403
+from architectures.dense import SCOPES as _DENSE
+
+SCOPES = tuple("dense_" + s for s in _DENSE)
+"""
+
+
+def _tree_digest(root: Path) -> str:
+    h = hashlib.sha256()
+    for p in sorted(root.rglob("*")):
+        if p.is_file() and "__pycache__" not in p.parts:
+            h.update(str(p.relative_to(root)).encode())
+            h.update(p.read_bytes())
+    return h.hexdigest()
+
+
+def test_architecture_added_as_new_files_runs_correct(tmp_path):
+    """A configuration naming a module that exists only as new files
+    (here in a temporary directory) runs to correct, and no file of the
+    benchmark changes."""
+    before = _tree_digest(HERE)
+    (tmp_path / "renamed.py").write_text(RENAMED)
+    cfg = harness.load_json(HERE / "testdata" / "tiny.json")
+    cfg["architecture"] = "renamed"
+    (tmp_path / "tiny-renamed.json").write_text(json.dumps(cfg))
+    cell = tiny_cell("tiny-renamed.json", tmp_path, tmp_path)
+    assert cell.arch.SCOPES == ("dense_attn", "dense_kv_write",
+                                "dense_mlp", "dense_lm_head")
+    assert Path(cell.arch.__file__).parent == tmp_path
+    res = run.run(cell, SEED + 1, WINDOW_S, False, time.perf_counter(),
+                  require_chip=False)
+    assert res["correct"] is True
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert _tree_digest(HERE) == before

@@ -1,7 +1,8 @@
 """The reduction to the program's layers (spans.py) on a hand-made trace
 in the layout of a TPU profile: idle gaps go to the innermost program
 region, never to a Python frame; device time goes to the scope of leaf
-ops only; the HLO op names come from a real trace's programs.  Beside
+ops only, among the scopes the cell's architecture module lists; the HLO
+op names come from a real trace's programs.  Beside
 it: every new reader is silent without a trace or counter, and
 ``xplane.reduce`` reads the hand trace exactly as before."""
 
@@ -19,9 +20,11 @@ sys.path[:0] = [str(HERE)]
 import harness  # noqa: E402
 import spans  # noqa: E402
 import xplane  # noqa: E402
+from architectures import dense  # noqa: E402
 from test_chipbench_xplane import MS, hand_trace  # noqa: E402
 
 LOOP = "jit(extend)/while/body"
+SCOPES = dense.SCOPES
 
 
 def region_trace():
@@ -50,7 +53,7 @@ def region_trace():
                 ["%fusion.3 = bf16[] fusion()", 6 * MS, 4 * MS,
                  f"{LOOP}/mlp/dot"],
                 ["%copy.79 = bf16[] copy()", 20 * MS, 20 * MS,
-                 "jit(extend)/kv_copy/dynamic_update_slice"],
+                 "jit(extend)/experts/dynamic_update_slice"],
                 ["%fusion.4 = bf16[] fusion()", 60 * MS, 10 * MS,
                  "jit(extend)/lm_head/dot"],
                 ["%copy.1 = s32[] copy()", 90 * MS, 5 * MS, ""],
@@ -61,7 +64,7 @@ def region_trace():
 
 
 def test_idle_goes_to_the_innermost_program_region():
-    red = spans.reduce(region_trace())
+    red = spans.reduce(region_trace(), SCOPES)
     idle = dict(red["idle_by_region"])
     # mid 15: put (the Python frame over it is ignored); mid 50:
     # spec.stage (not the runtime's device put); mid 80: spec.round;
@@ -72,16 +75,29 @@ def test_idle_goes_to_the_innermost_program_region():
     total = 1e-3 * (100 - 10 - 20 - 10 - 5)
     assert sum(idle.values()) == pytest.approx(total)
     assert sum(idle.values()) == pytest.approx(
-        xplane.reduce(_three(region_trace()))["window_s"]
-        - xplane.reduce(_three(region_trace()))["busy_s"])
+        xplane.reduce(region_trace())["window_s"]
+        - xplane.reduce(region_trace())["busy_s"])
 
 
 def test_device_time_goes_to_the_scope_of_leaf_ops():
-    dev = dict(spans.reduce(region_trace())["device_by_scope"])
-    # the loop's 10 ms is its body's, counted once
+    dev = dict(spans.reduce(region_trace(), SCOPES)["device_by_scope"])
+    # the loop's 10 ms is its body's, counted once; ``experts`` is no
+    # scope of the dense architecture's
     assert dev == pytest.approx({"attn": 0.004, "kv_write": 0.002,
-                                 "mlp": 0.004, "kv_copy": 0.020,
+                                 "mlp": 0.004, "lm_head": 0.010,
+                                 spans.OTHER: 0.025})
+
+
+def test_a_scope_of_the_cells_own_module_is_counted_by_name():
+    """Scopes come from the cell's architecture module: one that only a
+    new module lists reads under its own name, not ``other``."""
+    dev = dict(spans.reduce(region_trace(), SCOPES + ("experts",))[
+        "device_by_scope"])
+    assert dev == pytest.approx({"attn": 0.004, "kv_write": 0.002,
+                                 "mlp": 0.004, "experts": 0.020,
                                  "lm_head": 0.010, spans.OTHER: 0.005})
+    assert spans.scope_of("jit(f)/experts/dot", SCOPES) == spans.OTHER
+    assert spans.scope_of("jit(f)/experts/dot", ("experts",)) == "experts"
 
 
 def test_a_program_without_regions_or_scopes_reduces_to_none():
@@ -91,7 +107,7 @@ def test_a_program_without_regions_or_scopes_reduces_to_none():
         if not spans.REGION.match(ev[0])]
     tr["/device:TPU:0"]["XLA Ops"] = [
         ev[:3] + [""] for ev in tr["/device:TPU:0"]["XLA Ops"]]
-    assert spans.reduce(tr) == {"idle_by_region": None,
+    assert spans.reduce(tr, SCOPES) == {"idle_by_region": None,
                                 "device_by_scope": None}
 
 
@@ -126,14 +142,9 @@ def test_op_names_come_from_the_traces_programs():
         raw = fh.read()
     protos = spans._hlo_protos(raw)
     prog = next(p for p in protos if p.startswith("jit_f("))
-    scopes = {spans.scope_of(op)
+    scopes = {spans.scope_of(op, SCOPES)
               for op in spans._op_names(raw, protos[prog]).values()}
     assert {"attn", "mlp"} <= scopes
-
-
-def _three(pl):
-    return {p: {ln: [e[:3] for e in evs] for ln, evs in lines.items()}
-            for p, lines in pl.items()}
 
 
 def test_xplane_reduce_reads_the_hand_trace_as_before():
@@ -145,8 +156,8 @@ def test_xplane_reduce_reads_the_hand_trace_as_before():
 
 READERS = ["idle_ms_per_tick.sync", "idle_ms_per_tick.stage",
            "idle_ms_per_tick.sched", "dev_ms_per_tick.attn",
-           "dev_ms_per_tick.kv_write", "dev_ms_per_tick.kv_copy",
-           "dev_ms_per_tick.mlp", "tok_per_tick"]
+           "dev_ms_per_tick.kv_write", "dev_ms_per_tick.mlp",
+           "tok_per_tick"]
 
 
 def fake_rec(trace=None, counts=None):
@@ -166,13 +177,13 @@ def test_new_readers_are_silent_without_their_source(name):
 
 
 def test_new_readers_read_per_tick():
-    red = spans.reduce(region_trace())
-    rec = fake_rec(trace=dict(xplane.reduce(_three(region_trace())), **red))
+    red = spans.reduce(region_trace(), SCOPES)
+    rec = fake_rec(trace=dict(xplane.reduce(region_trace()), **red))
     rec.snap_start = types.SimpleNamespace(counts={"committed_tokens": 10})
     rec.snap_end = types.SimpleNamespace(counts={"committed_tokens": 70})
     got = {n: harness.reader(n)(rec) for n in READERS}
     assert got == pytest.approx({
         "idle_ms_per_tick.sync": 0.0, "idle_ms_per_tick.stage": 15.0,
         "idle_ms_per_tick.sched": 10.0, "dev_ms_per_tick.attn": 2.0,
-        "dev_ms_per_tick.kv_write": 1.0, "dev_ms_per_tick.kv_copy": 10.0,
-        "dev_ms_per_tick.mlp": 2.0, "tok_per_tick": 30.0})
+        "dev_ms_per_tick.kv_write": 1.0, "dev_ms_per_tick.mlp": 2.0,
+        "tok_per_tick": 30.0})

@@ -1,7 +1,9 @@
 """Reduction of a profiler trace (``*.xplane.pb``) to device metrics.
 
-``planes`` turns the trace into plain data, {plane: {line: [[name,
-start_ns, dur_ns], ...]}}; ``reduce`` computes from that:
+``spans.planes`` turns the trace into plain data, {plane: {line: [[name,
+start_ns, dur_ns], ...]}} (an "XLA Ops" event carries its HLO
+``op_name`` as a fourth field, which this module ignores); ``reduce``
+computes from that:
 
   window_s    the traced window: the host span ``chipbench.window``
   busy_s      the union of the intervals in which an operation ran on a
@@ -35,20 +37,6 @@ def find(trace_dir: str) -> str:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     return max(paths, key=os.path.getmtime)
-
-
-def planes(path: str) -> Planes:
-    import jax
-    data = jax.profiler.ProfileData.from_file(path)
-    out: Planes = {}
-    for plane in data.planes:
-        lines = {}
-        for line in plane.lines:
-            lines[line.name] = [[ev.name, float(ev.start_ns),
-                                 float(ev.duration_ns)]
-                                for ev in line.events]
-        out[plane.name] = lines
-    return out
 
 
 def _is_device(name: str) -> bool:
@@ -110,7 +98,7 @@ def reduce(pl: Planes, top: int = 10) -> Optional[Dict]:
     gaps: List[Tuple[float, float]] = []
     for lines in devices.values():
         iv = []
-        for n, s, d in lines[OPS_LINE]:
+        for n, s, d, *_ in lines[OPS_LINE]:
             a, b = max(s, w0), min(s + d, w1)
             if b > a:
                 iv.append((a, b))
